@@ -7,7 +7,9 @@ This module is the execution substrate behind it:
 
   * :class:`TransferStream` — one host→device staging queue per channel
     (pinned to a physical mesh device by
-    ``distributed.sharding.io_channel_devices``).  ``put`` issues an
+    ``distributed.sharding.io_channel_devices``).  A channel whose device
+    does not hold the live cache hands its staged bytes to the cache's
+    device by an explicit copy before the scatter.  ``put`` issues an
     *asynchronous* ``jax.device_put`` and only blocks on the oldest
     in-flight buffer beyond ``depth``: with the default depth of 2, op
     k+1's host→device copy is in flight while op k's dequant-scatter
@@ -54,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.kv_restore import kv_restore_scatter
+from repro.kernels.kv_restore.ops import pick_backend
 
 ATTN_FIELDS = ("k", "v", "ckv")
 
@@ -126,8 +129,15 @@ class RestoreDatapath:
             depth=depth)]
         self.backend = backend
         self.measure = measure
-        self.kernel_launches = 0       # fused dequant-scatter launches
-        self.resident_copies = 0       # device-to-device run scatters
+        # staged runs, by what scattered them: the Pallas kernel, or the
+        # jitted oracle (off TPU, or unaligned shapes on TPU)
+        self.pallas_launches = 0
+        self.oracle_runs = 0
+        self.resident_copies = 0       # device-local copies of HBM runs
+        # staged runs whose channel device is not the cache's: the bytes
+        # move chip to chip explicitly before the scatter
+        self.device_moves = 0
+        self.device_move_bytes = 0
         self.runs = 0
         self.ops = 0
         self.last_op_dispatches = 0    # copy dispatches of the latest op
@@ -181,25 +191,33 @@ class RestoreDatapath:
                 self.resident_copies += 1
             else:
                 host, nbytes = self._pack_host(run, fields, cs, a)
-                dev = stream.put(host)
+                dev = self._to_cache_device(stream.put(host), cache, nbytes)
                 dispatches += 1                    # one staged copy per run
                 moved += nbytes
                 staged = {f: dev[f] for f in fields}
                 kpos_dev = dev["kpos"]
                 scales_dev = ({f: dev[f + "__s"] for f in fields}
                               if form == "int8" else None)
-                self.kernel_launches += 1
 
             # one fused (dequantizing) scatter per run, all fields in the
             # launch; resident runs are device-local copies and take the
             # jitted oracle (XLA fuses them into one update per field)
             views = [cache[f].reshape(a, s, -1) for f in fields]
+            staged_l = [staged[f] for f in fields]
+            backend = "ref"
+            if form != "hbm":
+                backend = pick_backend(views, staged_l, t0=r0, chunk_size=cs,
+                                       backend=self.backend)
+                if backend == "ref":
+                    self.oracle_runs += 1
+                else:
+                    self.pallas_launches += 1
             out = kv_restore_scatter(
-                views, [staged[f] for f in fields],
+                views, staged_l,
                 None if scales_dev is None else [scales_dev[f]
                                                  for f in fields],
                 t0=r0, slot_lo=s_lo, n_slots=s_hi - s_lo, chunk_size=cs,
-                backend="ref" if form == "hbm" else self.backend)
+                backend=backend)
             for f, o in zip(fields, out):
                 cache[f] = o.reshape(cache[f].shape)
             dispatches += 1
@@ -223,6 +241,20 @@ class RestoreDatapath:
             stream.note(secs, moved)
             self._last_secs = secs
         return cache
+
+    def _to_cache_device(self, dev: dict, cache: dict, nbytes: int) -> dict:
+        """Staged buffers land on their channel's device; the scatter runs
+        where the live cache is.  A channel pinned to another chip hands
+        its bytes over by an explicit device-to-device copy (ICI on a
+        multi-chip host) — never by letting the scatter follow the staged
+        arrays off the cache's chip."""
+        target = next(iter(cache["kpos"].devices()))
+        src = next(iter(next(iter(dev.values())).devices()))
+        if src == target:
+            return dev
+        self.device_moves += 1
+        self.device_move_bytes += nbytes
+        return jax.device_put(dev, target)
 
     # ------------------------------------------------------------------
     @staticmethod

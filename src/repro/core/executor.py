@@ -435,8 +435,8 @@ class RestorationExecutor:
                 # deterministic pseudo-embedding keyed on the position
                 key = jax.random.fold_in(jax.random.PRNGKey(0), live["pos"])
                 inp = jax.random.normal(key, (1, cfg.d_model), jnp.float32)
-            logits, cache = m.decode_step(self.params, inp, live["cache"],
-                                          live["pos"])
+            logits, cache = m.decode_step_jit(self.params, inp, live["cache"],
+                                              live["pos"])
             live["cache"] = cache
             live["last_logits"] = logits
             if "kpos" in cache:

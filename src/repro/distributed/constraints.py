@@ -1,10 +1,10 @@
 """Sharding-constraint helper usable from model code.
 
 ``constrain(x, spec)`` applies ``with_sharding_constraint`` against the
-ambient mesh (the one the launcher traces under); axis names missing from
-the mesh are stripped, and with no mesh (single-device tests) it is a no-op —
-so model code can express distribution *hints* without depending on how it
-is launched.
+ambient mesh (the one the launcher activates with ``jax.set_mesh``); axis
+names missing from the mesh are stripped, and with no mesh (single-device
+tests) it is a no-op — so model code can express distribution *hints*
+without depending on how it is launched.
 """
 from __future__ import annotations
 
@@ -14,14 +14,9 @@ from jax.sharding import PartitionSpec as P
 
 
 def _ambient_mesh():
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh`` (abstract inside a trace), or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def constrain(x, *spec):

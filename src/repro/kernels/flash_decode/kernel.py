@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -97,7 +95,7 @@ def flash_decode(q, k, v, kpos, q_pos, *, scale: float, window: int = 0,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(scalars, q, k, v, kpos)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _absmax_kernel(x_ref, amax_ref):
     i = pl.program_id(0)
@@ -63,7 +61,7 @@ def kv_quantize_2d(x, *, br: int = 256, interpret: bool = False):
         in_specs=[pl.BlockSpec((br, c), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, c), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, c), jnp.float32),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x)
     scales = jnp.maximum(amax, 1e-12) / 127.0
@@ -74,7 +72,7 @@ def kv_quantize_2d(x, *, br: int = 256, interpret: bool = False):
                   pl.BlockSpec((1, c), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), jnp.int8),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, scales)
     return q, scales
@@ -94,6 +92,6 @@ def kv_dequantize_2d(q, scales, *, dtype=jnp.bfloat16, br: int = 256,
                   pl.BlockSpec((1, c), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(q, scales)

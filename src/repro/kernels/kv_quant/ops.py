@@ -23,11 +23,17 @@ def _pad2d(x2, br):
     return x2, r, c
 
 
+def resolve_backend(backend: str = "auto") -> str:
+    """``auto`` is the Pallas kernel on TPU and the jnp oracle elsewhere."""
+    if backend == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return backend
+
+
 def kv_quantize(x, *, backend: str = "auto", br: int = 256):
     """Per-channel int8 quantization of a KV chunk.  Returns
     (q int8, shape of ``x``; scales f32, shape ``(x.shape[-1],)``)."""
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend = resolve_backend(backend)
     if backend == "ref":
         return ref.kv_quantize_ref(x)
     x2 = x.reshape(-1, x.shape[-1])
@@ -40,8 +46,7 @@ def kv_quantize(x, *, backend: str = "auto", br: int = 256):
 def kv_dequantize(q, scales, dtype=jnp.bfloat16, *, backend: str = "auto",
                   br: int = 256):
     """Inverse of :func:`kv_quantize` (lossy)."""
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend = resolve_backend(backend)
     if backend == "ref":
         return ref.kv_dequantize_ref(q, scales, dtype)
     q2 = q.reshape(-1, q.shape[-1])

@@ -22,7 +22,8 @@ Layout per field f (channels = flattened trailing axes, token axis 1):
 
 Grid ``(n_slots, n_chunks)``; block shapes ``(1, cs, C_f)`` with the out
 index map offset by ``(slot_lo, t0 // cs)`` so a sub-span of slots and a
-mid-prefix token range address the right cache region.  The dequant body
+mid-prefix token range address the right cache region; slots outside the
+span keep their bytes (aliased, never visited by the grid).  The dequant body
 is bit-identical to ``kv_quant._dequant_kernel`` (f32 multiply, one cast).
 """
 from __future__ import annotations
@@ -32,8 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _restore_kernel(nf, quant, *refs):
@@ -50,18 +50,21 @@ def _restore_kernel(nf, quant, *refs):
         out_refs[f][...] = y
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("t0", "slot_lo", "cs", "interpret"))
+@functools.partial(jax.jit, static_argnames=("t0", "slot_lo", "n_slots",
+                                             "cs", "interpret"))
 def kv_restore_call(caches, staged, scales, *, t0: int, slot_lo: int,
-                    cs: int, interpret: bool = False):
+                    n_slots: int, cs: int, interpret: bool = False):
     """caches/staged: tuples of (A, S, C_f) / (A, T, C_f); scales: tuple of
-    (n_chunks, 1, C_f) f32 or None.  T % cs == 0 and t0 % cs == 0 required
-    (the ops wrapper guarantees both).  Returns the updated caches."""
+    (n_chunks, 1, C_f) f32 or None.  Writes slots [slot_lo, slot_lo +
+    n_slots) only, so one stage of a multi-stage split restores its own
+    sub-span.  T % cs == 0 and t0 % cs == 0 required (the ops wrapper
+    guarantees both).  Returns the updated caches."""
     nf = len(caches)
     quant = scales is not None
     t = staged[0].shape[1]
     n_chunks = t // cs
-    n_slots = staged[0].shape[0] - slot_lo
+    assert 0 <= slot_lo and slot_lo + n_slots <= staged[0].shape[0], \
+        (slot_lo, n_slots, staged[0].shape)
     b0 = t0 // cs
 
     def _cache_map(a, i):
@@ -90,7 +93,7 @@ def kv_restore_call(caches, staged, scales, *, t0: int, slot_lo: int,
         out_specs=cache_specs,
         out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in caches],
         input_output_aliases={f: f for f in range(nf)},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(loga_ref, b_ref, h0_ref, o_ref, hlast_ref, h_sc, *, bs: int, ns: int):
     t_blk = pl.program_id(2)
@@ -71,7 +69,7 @@ def rglru_scan(log_a, b, h0, *, bs: int = 256, bw: int = 512, interpret: bool = 
             jax.ShapeDtypeStruct((bsz, w), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b, h0)

@@ -164,7 +164,7 @@ def dryrun_cell(arch: str, shape_name: str, multi_pod: bool,
     specs = input_specs(cfg, shape)
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = AdamWConfig()
             # microbatch = one sequence per (pod×data) batch shard: bounds

@@ -13,6 +13,13 @@ and restarts them from the store:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --real \
       --requests 4 --system cacheflow --kv-quant int8 --store-dir /tmp/kv
 
+Real execution at published widths (one TPU v5e): ``--layers`` keeps every
+width of the config and cuts only its depth; ``chip_smoke.py`` at the repo
+root runs this path and checks it:
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --real \
+      --layers 18 --dtype bfloat16 --chunk-size 128 --prefix-len 2048 \
+      --new-len 64 --requests 4 --max-batch 2
+
 Schedule capture & replay (see repro/core/trace.py): ``--trace-out t.json``
 records the restoration schedule of any run; ``--replay t.json`` re-executes
 a captured schedule decision-for-decision with pinned durations —
@@ -43,15 +50,18 @@ in https://ui.perfetto.dev.  Any captured trace renders offline with
 from __future__ import annotations
 
 import argparse
-import json
+import dataclasses
 import sys
+from typing import List, Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.config import HARDWARE, IO_BANDWIDTHS
 from repro.configs import get_config
 from repro.core.baselines import BASELINES
 from repro.core.trace import ScheduleTrace, TraceRecorder, replay_trace
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serving import (ChunkStore, RealServingEngine, Request,
                            SimServingEngine, TieredKVStore, generate)
@@ -82,6 +92,135 @@ def _save_metrics(telemetry: dict, path: str):
     with open(path, "w") as f:
         f.write(dumps_report(telemetry))
     print(f"# telemetry snapshot -> {path}", file=sys.stderr)
+
+
+def build_real_model(arch: str, *, layers: Optional[int] = None,
+                     dtype: str = "float32", seed: int = 0):
+    """The model of the real path, with random weights drawn from ``seed``.
+
+    Without ``layers`` it is the config's ``.reduced()`` CPU-test model.
+    With ``layers`` every published width of the config is kept and only
+    its depth is cut to the first ``layers`` layers.  ``dtype`` is both the
+    parameter and the compute dtype."""
+    base = get_config(arch)
+    if layers is None:
+        cfg = base.reduced()
+    else:
+        if not 1 <= layers <= base.num_layers:
+            raise SystemExit(f"--layers {layers}: '{arch}' has "
+                             f"{base.num_layers} layers")
+        cfg = dataclasses.replace(base, name=f"{base.name}-{layers}L",
+                                  num_layers=layers)
+    dt = jnp.dtype(dtype)
+    model = build_model(cfg, param_dtype=dt, compute_dtype=dt)
+    return model, model.init(jax.random.PRNGKey(seed))
+
+
+def real_requests(n: int, *, prefix_len: Optional[int] = None,
+                  new_len: int = 16, decode_len: int = 8,
+                  preempt: str = "none", id_prefix: str = "r"
+                  ) -> List[Request]:
+    """The real path's request stream: prefixes of ``prefix_len`` tokens
+    (64+32·i when None).  With a preemption policy armed, arrivals are
+    staggered and every other request is urgent so admission pressure
+    exercises it; without one, every request arrives at t=0."""
+    reqs = []
+    for i in range(n):
+        plen = prefix_len if prefix_len is not None else 64 + 32 * i
+        if preempt != "none":
+            reqs.append(Request(f"{id_prefix}{i}", 0.1 * i, prefix_len=plen,
+                                new_len=new_len, decode_len=decode_len,
+                                priority=i % 2,
+                                deadline=0.1 * i + (2.0 if i % 2 else 120.0)))
+        else:
+            reqs.append(Request(f"{id_prefix}{i}", 0.0, prefix_len=plen,
+                                new_len=new_len, decode_len=decode_len))
+    return reqs
+
+
+def serve_real(model, params, requests: List[Request], *,
+               system: str = "cacheflow", stages: int = 2,
+               chunk_size: int = 16, max_batch: int = 8,
+               io_channels: int = 1, kv_quant: str = "none",
+               kv_tier: str = "host", store_dir: Optional[str] = None,
+               datapath: str = "fused", preempt: str = "none",
+               evict: bool = False, admission: str = "continuous",
+               prefetch: bool = False, sanitize: Optional[bool] = None,
+               telemetry: Optional[bool] = None, verify: bool = True,
+               trace=None):
+    """Serve ``requests`` through RealServingEngine → EngineCore →
+    RestorationExecutor → ChunkStore/RestoreDatapath, verifying every
+    restored cache against its full-prefill reference (``verify``).
+
+    Restoration reads the MATERIALIZED chunk store: prefix KV lives as
+    content-addressed, deduplicated chunks across hbm/host/disk tiers and
+    load ops move their actual bytes.  Returns ``(report, ServingReport,
+    engine)``; ``report`` is the JSON block ``serve --real`` prints."""
+    cfg = model.cfg
+    store = None
+    if not cfg.attn_window:
+        store = ChunkStore(chunk_size=chunk_size, quant=kv_quant,
+                           store_dir=store_dir, default_tier=kv_tier)
+    eng = RealServingEngine(model, params, system=system,
+                            stages=min(stages, 2), chunk_size=chunk_size,
+                            max_batch=max_batch, io_channels=io_channels,
+                            preempt=preempt, evict=evict,
+                            admission=admission, prefetch=prefetch,
+                            kvstore=store, datapath=datapath,
+                            sanitize=sanitize, telemetry=telemetry)
+    rep = eng.serve(requests, verify=verify, trace=trace)
+    out = {"system": system, "mode": "real",
+           "model": {"name": cfg.name, "layers": cfg.num_layers,
+                     "d_model": cfg.d_model,
+                     "dtype": jnp.dtype(model.param_dtype).name},
+           "chunk_size": chunk_size,
+           "admission": admission,
+           "lifecycle": rep.stats,
+           "preemptions": sum(rep.preemptions.values()),
+           "compute_busy": round(rep.compute_busy, 3),
+           "io_busy": round(rep.io_busy, 3),
+           "decode_busy": round(rep.decode_busy, 3),
+           "overlap_decode_restore": round(rep.overlap_decode_restore, 3)}
+    if rep.sanitizer is not None:
+        out["sanitizer"] = rep.sanitizer
+    if store is not None:
+        out["storage"] = {
+            "chunks": len(store.chunks), "dedup_hits": store.dedup_hits,
+            "bytes_put": store.bytes_put,
+            "bytes_transferred": store.bytes_transferred,
+            "io_hits": store.io_hits,
+            "skipped_transfers": store.skipped_transfers,
+            "store_misses": store.store_misses,
+            "forks": store.forks,
+            "pool_blocks": store.pool.live_blocks(),
+            "cow_copies": store.pool.cow_copies,
+            "cow_bytes": store.pool.bytes_copied,
+            "kv_quant_calls": dict(store.quant_calls)}
+    if eng.datapath is not None:
+        dp, ex = eng.datapath, eng.executor
+        out["datapath"] = {
+            "mode": datapath,
+            "channels": len(dp.streams),
+            "pallas_launches": dp.pallas_launches,
+            "oracle_runs": dp.oracle_runs,
+            "resident_copies": dp.resident_copies,
+            "note": "resident_copies are device-local copies of "
+                    "HBM-resident runs, not oracle fallbacks",
+            "device_moves": dp.device_moves,
+            "device_move_bytes": dp.device_move_bytes,
+            "staged_puts": sum(st.puts for st in dp.streams),
+            "staged_bytes": sum(st.bytes_staged for st in dp.streams),
+            "fused_loads": ex.fused_loads,
+            "legacy_loads": ex.legacy_loads,
+            "load_dispatches": ex.load_dispatches,
+            # measured host→device bytes/sec per engine channel (None
+            # until a channel carries a measured transfer)
+            "channel_gbps": [round(b / 1e9, 6) if b else None
+                             for b in dp.bandwidths()]}
+    elif store is not None:
+        out["datapath"] = {"mode": "legacy",
+                           "load_dispatches": eng.executor.load_dispatches}
+    return out, rep, eng
 
 
 def _replay(args) -> None:
@@ -277,7 +416,30 @@ def main():
                          "flow arrows, aborted-op markers and counter "
                          "tracks (queue depth, tier bytes, per-channel "
                          "bandwidth); works with --replay too")
-    ap.add_argument("--real", action="store_true", help="run a reduced model for real")
+    ap.add_argument("--real", action="store_true",
+                    help="run the model for real (the config's reduced "
+                         "CPU-test widths unless --layers is given)")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="real mode: keep every published width of --arch "
+                         "and cut only its depth to N layers.  For qwen3-8b "
+                         "on one 16 GB TPU v5e use 18 of 36 layers (one of "
+                         "two pipeline stages) with --dtype bfloat16: about "
+                         "9.44 GB of weights, leaving about 6.5 GB for "
+                         "caches; KV is 4 KiB per token per layer "
+                         "(kv_bytes_per_token), 72 KiB per token at 18 "
+                         "layers")
+    ap.add_argument("--dtype", default="float32",
+                    choices=["float32", "bfloat16"],
+                    help="real mode: parameter and compute dtype")
+    ap.add_argument("--chunk-size", type=int, default=16,
+                    help="real mode: restoration chunk (and store block) "
+                         "size in tokens; use >= 128 on a TPU, where every "
+                         "chunk x layer is one dispatch")
+    ap.add_argument("--prefix-len", type=int, default=None,
+                    help="real mode: prefix tokens of every request "
+                         "(default 64+32*i for request i)")
+    ap.add_argument("--new-len", type=int, default=16,
+                    help="real mode: new-turn suffix tokens per request")
     ap.add_argument("--trace-out", metavar="PATH",
                     help="capture the restoration schedule to a JSON trace")
     ap.add_argument("--replay", metavar="PATH",
@@ -285,6 +447,7 @@ def main():
                          "instead of scheduling fresh; --real replays it "
                          "on-device with per-request cache verification")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.admission == "gang" and args.preempt != "none":
         raise SystemExit("--admission gang is the run-to-completion "
@@ -304,85 +467,23 @@ def main():
         else None
 
     if args.real:
-        cfg = get_config(args.arch).reduced()
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(0))
-        # real mode restores from the MATERIALIZED chunk store: prefix KV
-        # lives as content-addressed, deduplicated chunks across
-        # hbm/host/disk tiers and load ops move its actual bytes
-        store = None
-        if not cfg.attn_window:
-            store = ChunkStore(chunk_size=16, quant=args.kv_quant,
-                               store_dir=args.store_dir,
-                               default_tier=args.kv_tier)
-        eng = RealServingEngine(model, params, system=args.system,
-                                stages=min(args.stages, 2), chunk_size=16,
-                                max_batch=args.max_batch,
-                                io_channels=args.io_channels,
-                                preempt=args.preempt, evict=args.evict,
-                                admission=args.admission,
-                                prefetch=args.prefetch,
-                                kvstore=store, datapath=args.datapath,
-                                sanitize=args.sanitize or None,
-                                telemetry=args.telemetry or None)
-        decode_len = args.decode_len if args.decode_len >= 0 else 8
-        # with a preemption policy armed, stagger arrivals and mark every
-        # other request urgent so admission pressure actually exercises it;
-        # without one, keep the classic simultaneous-arrival smoke exactly
-        if args.preempt != "none":
-            reqs = [Request(f"r{i}", 0.1 * i, prefix_len=64 + 32 * i,
-                            new_len=16, decode_len=decode_len, priority=i % 2,
-                            deadline=0.1 * i + (2.0 if i % 2 else 120.0))
-                    for i in range(args.requests)]
-        else:
-            reqs = [Request(f"r{i}", 0.0, prefix_len=64 + 32 * i, new_len=16,
-                            decode_len=decode_len)
-                    for i in range(args.requests)]
-        rep = eng.serve(reqs, trace=recorder)
+        model, params = build_real_model(args.arch, layers=args.layers,
+                                         dtype=args.dtype, seed=args.seed)
+        reqs = real_requests(
+            args.requests, prefix_len=args.prefix_len, new_len=args.new_len,
+            decode_len=args.decode_len if args.decode_len >= 0 else 8,
+            preempt=args.preempt)
+        out, rep, _ = serve_real(
+            model, params, reqs, system=args.system, stages=args.stages,
+            chunk_size=args.chunk_size, max_batch=args.max_batch,
+            io_channels=args.io_channels, kv_quant=args.kv_quant,
+            kv_tier=args.kv_tier, store_dir=args.store_dir,
+            datapath=args.datapath, preempt=args.preempt, evict=args.evict,
+            admission=args.admission, prefetch=args.prefetch,
+            sanitize=args.sanitize or None,
+            telemetry=args.telemetry or None, trace=recorder)
         if args.trace_out:
             _save_trace(recorder, args.trace_out, arch=args.arch)
-        out = {"system": args.system, "mode": "real",
-               "admission": args.admission,
-               "lifecycle": rep.stats,
-               "preemptions": sum(rep.preemptions.values()),
-               "compute_busy": round(rep.compute_busy, 3),
-               "io_busy": round(rep.io_busy, 3),
-               "decode_busy": round(rep.decode_busy, 3),
-               "overlap_decode_restore": round(rep.overlap_decode_restore, 3)}
-        if rep.sanitizer is not None:
-            out["sanitizer"] = rep.sanitizer
-        if store is not None:
-            out["storage"] = {
-                "chunks": len(store.chunks), "dedup_hits": store.dedup_hits,
-                "bytes_put": store.bytes_put,
-                "bytes_transferred": store.bytes_transferred,
-                "io_hits": store.io_hits,
-                "skipped_transfers": store.skipped_transfers,
-                "store_misses": store.store_misses,
-                "forks": store.forks,
-                "pool_blocks": store.pool.live_blocks(),
-                "cow_copies": store.pool.cow_copies,
-                "cow_bytes": store.pool.bytes_copied}
-        if eng.datapath is not None:
-            dp, ex = eng.datapath, eng.executor
-            out["datapath"] = {
-                "mode": args.datapath,
-                "channels": len(dp.streams),
-                "kernel_launches": dp.kernel_launches,
-                "resident_copies": dp.resident_copies,
-                "staged_puts": sum(s.puts for s in dp.streams),
-                "staged_bytes": sum(s.bytes_staged for s in dp.streams),
-                "fused_loads": ex.fused_loads,
-                "legacy_loads": ex.legacy_loads,
-                "load_dispatches": ex.load_dispatches,
-                # measured host→device bytes/sec per engine channel (None
-                # until a channel carries a measured transfer)
-                "channel_gbps": [round(b / 1e9, 6) if b else None
-                                 for b in dp.bandwidths()]}
-        elif store is not None:
-            out["datapath"] = {"mode": "legacy",
-                               "load_dispatches":
-                                   eng.executor.load_dispatches}
         _emit_outputs(out, rep, recorder, args)
         return
 

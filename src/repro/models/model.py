@@ -29,12 +29,15 @@ from repro.models.layers import (apply_norm, embed_init, init_norm,
                                  sinusoidal_positions)
 
 
-def _stack(trees):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-
-
 def _index(tree, i):
     return jax.tree.map(lambda a: a[i], tree)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_layer(stack, layer, j):
+    """Write one layer's params into slot ``j`` of the stacked params, in
+    place (the stack is donated)."""
+    return jax.tree.map(lambda s, a: s.at[j].set(a), stack, layer)
 
 
 class Model:
@@ -61,6 +64,13 @@ class Model:
         else:
             self.prefix_len = 0
         self.scan_len = cfg.num_layers - self.prefix_len
+        # compiled entry points of the serving executor.  Built once per
+        # Model (models are cached per config), so every executor shares
+        # their compile caches.  The layer index and cache slot are traced:
+        # one program serves every layer of a kind at a given chunk shape.
+        self._layer_step = jax.jit(self._layer_step_impl,
+                                   static_argnames=("kind",))
+        self.decode_step_jit = jax.jit(self.decode_step)
 
     # ------------------------------------------------------------------
     # Params
@@ -74,12 +84,29 @@ class Model:
         if not cfg.tie_embeddings:
             p["unembed"] = embed_init(keys[-2], (cfg.d_model, cfg.vocab_size), self.param_dtype)
         p["final_norm"] = init_norm(cfg.norm, cfg.d_model, self.param_dtype)
-        layers = [tfm.init_layer(keys[i], cfg, i, self.param_dtype)
-                  for i in range(cfg.num_layers)]
-        p["prefix_layers"] = layers[: self.prefix_len]
+        p["prefix_layers"] = [tfm.init_layer(keys[i], cfg, i, self.param_dtype)
+                              for i in range(self.prefix_len)]
         if self.scan_len:
-            p["scan_layers"] = _stack(layers[self.prefix_len:])
+            p["scan_layers"] = self._init_scan_layers(keys)
         return p
+
+    def _init_scan_layers(self, keys) -> dict:
+        """Stacked params of the scan segment, written layer by layer into
+        preallocated arrays: only one layer's params exist beside the stack
+        (a list of layers + ``jnp.stack`` would hold every layer twice,
+        which does not fit one chip at published widths).  Each layer is
+        drawn exactly as before, so the values are bit-identical."""
+        cfg, lo = self.cfg, self.prefix_len
+        first = tfm.init_layer(keys[lo], cfg, lo, self.param_dtype)
+        stack = jax.tree.map(
+            lambda a: jnp.zeros((self.scan_len,) + a.shape, a.dtype), first)
+        stack = _set_layer(stack, first, 0)
+        del first
+        for i in range(lo + 1, cfg.num_layers):
+            stack = _set_layer(
+                stack, tfm.init_layer(keys[i], cfg, i, self.param_dtype),
+                i - lo)
+        return stack
 
     def param_specs(self) -> dict:
         return jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
@@ -167,10 +194,23 @@ class Model:
                                 states)[:2]
 
     def layer_chunk(self, params, i: int, x, positions, cache):
-        """One layer over a chunk, attending to + updating the cache."""
+        """One layer over a chunk, attending to + updating the cache
+        (compiled; see ``_layer_step``)."""
         kind, slot = self.slots[i]
-        return self._layer_cached(self.layer_params(params, i), kind, slot, x,
-                                  positions, dict(cache))
+        if i < self.prefix_len:
+            return self._layer_step(params["prefix_layers"][i], None, x,
+                                    positions, dict(cache), slot, kind=kind)
+        return self._layer_step(params["scan_layers"], i - self.prefix_len,
+                                x, positions, dict(cache), slot, kind=kind)
+
+    def _layer_step_impl(self, layer_params, j, x, positions, cache, slot, *,
+                         kind):
+        """``layer_params`` is one layer's params (``j`` None) or the
+        stacked scan segment, of which layer ``j`` runs."""
+        if j is not None:
+            layer_params = _index(layer_params, j)
+        return self._layer_cached(layer_params, kind, slot, x, positions,
+                                  cache)
 
     def forward(self, params, inputs, positions=None, collect_cache: bool = False):
         """Whole-sequence forward.
@@ -344,11 +384,8 @@ class Model:
             x, new_cache = jax.lax.scan(body, x, (params["scan_layers"], cache))
             return x, new_cache
 
-        cache = dict(cache)
         for i in range(lo, hi):
-            kind, slot = self.slots[i]
-            x, cache = self._layer_cached(self.layer_params(params, i), kind, slot,
-                                          x, positions, cache)
+            x, cache = self.layer_chunk(params, i, x, positions, cache)
         return x, cache
 
     def decode_step_append(self, params, tokens, cache, tail, tail_len, pos):

@@ -78,7 +78,9 @@ METRIC_CATALOG = {
         "type": "gauge", "labels": ("channel",), "layer": "core/datapath"},
     "datapath.channel_bytes_total": {
         "type": "counter", "labels": ("channel",), "layer": "core/datapath"},
-    "datapath.kernel_launches_total": {
+    "datapath.pallas_launches_total": {
+        "type": "counter", "labels": (), "layer": "core/datapath"},
+    "datapath.oracle_runs_total": {
         "type": "counter", "labels": (), "layer": "core/datapath"},
     # ---- storage tiers (storage/placement.py, storage/chunkstore.py) ----
     "storage.tier_used_bytes": {

@@ -183,7 +183,9 @@ class Telemetry:
         if dp is None:
             return
         self.registry.counter(
-            "datapath.kernel_launches_total").inc(dp.kernel_launches)
+            "datapath.pallas_launches_total").inc(dp.pallas_launches)
+        self.registry.counter(
+            "datapath.oracle_runs_total").inc(dp.oracle_runs)
         for c, (stream, bw) in enumerate(zip(dp.streams, dp.bandwidths())):
             self.registry.counter(
                 "datapath.channel_bytes_total",

@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.kv_quant import kv_dequantize, kv_quantize
+from repro.kernels.kv_quant.ops import resolve_backend
 from repro.models.kvcache import BlockPool
 from repro.storage.placement import PlacementCore, Tier
 
@@ -156,6 +157,9 @@ class ChunkStore:
         self.bytes_transferred = 0       # bytes moved toward HBM (post-quant)
         self.store_misses = 0
         self.max_scale = 0.0             # worst per-channel int8 scale seen
+        # kv_quant codec calls by the backend that ran them ("pallas" on
+        # TPU, "ref" elsewhere)
+        self.quant_calls: Dict[str, int] = {}
 
     # ------------------------------------------------------------------
     # Placement-core callbacks
@@ -251,10 +255,16 @@ class ChunkStore:
         for f, arr in raw.items():
             if f == "kpos":
                 continue
-            q, scales = kv_quantize(jnp.asarray(arr))
+            q, scales = kv_quantize(jnp.asarray(arr),
+                                    backend=self._quant_backend())
             self.max_scale = max(self.max_scale, float(jnp.max(scales)))
             out[f] = {"q": np.asarray(q), "scales": np.asarray(scales)}
         return out
+
+    def _quant_backend(self) -> str:
+        backend = resolve_backend()
+        self.quant_calls[backend] = self.quant_calls.get(backend, 0) + 1
+        return backend
 
     def _decode_device(self, key: str) -> dict:
         """The chunk as device arrays in its original dtypes (the HBM view
@@ -277,7 +287,8 @@ class ChunkStore:
             if isinstance(rep, dict):              # quantized
                 dev[f] = kv_dequantize(jnp.asarray(rep["q"]),
                                        jnp.asarray(rep["scales"]),
-                                       dtype=c.dtypes[f])
+                                       dtype=c.dtypes[f],
+                                       backend=self._quant_backend())
             else:
                 dev[f] = jnp.asarray(rep)
         return dev

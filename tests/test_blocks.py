@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.core.trace import TraceRecorder, replay_trace
@@ -169,7 +169,7 @@ def test_truncate_drops_tail_refs():
 
 
 @pytest.mark.property
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(ops=st.lists(st.integers(0, 2), min_size=1, max_size=24),
        n0=st.integers(1, 3 * BS))
 def test_refcount_conservation_under_fork_append_free(ops, n0):

@@ -233,6 +233,34 @@ def test_io_channel_devices_and_stream_routing():
     assert dp.stream_for(4) is dp.streams[1]      # modulo wrap
 
 
+@pytest.mark.parametrize("backend,kv_heads,pallas", [
+    ("auto", 2, False),           # CPU: the jitted oracle
+    ("pallas", 2, False),         # 64 channels, not lane-aligned: oracle
+    ("interpret", 4, True),       # 128 channels: the kernel runs
+], ids=["auto-cpu", "unaligned", "aligned"])
+def test_staged_runs_counted_by_what_scattered_them(backend, kv_heads,
+                                                    pallas):
+    """Every staged run counts once, as a Pallas launch or as an oracle
+    run — an unaligned shape that falls back to the oracle is never
+    counted as a kernel launch."""
+    cfg = get_config("qwen3-8b").reduced(num_kv_heads=kv_heads)
+    m = build_model(cfg)
+    store = ChunkStore(chunk_size=8, default_tier="host")
+    dp = RestoreDatapath.for_channels(1, backend=backend)
+    ex = RestorationExecutor(m, m.init(RNG), chunk_size=16,
+                             chunk_store=store, datapath=dp)
+    inputs = jax.random.randint(RNG, (1, 40), 0, cfg.vocab_size)
+    ex.remember("r", inputs)
+    plans = make_baseline_plans("lmcache", "r", 40, chunk_size=16, l_delta=0,
+                                num_layers=cfg.num_layers)
+    ex.restore("r", plans=plans)
+    ex.verify("r")
+    staged = sum(s.puts for s in dp.streams)
+    assert staged > 0
+    assert dp.pallas_launches + dp.oracle_runs == staged
+    assert dp.pallas_launches == (staged if pallas else 0)
+
+
 def test_engine_channel_hint_reaches_executor():
     from repro.core.engine_core import RealBackend
     ex, _ = _executor(datapath=True)

@@ -72,6 +72,7 @@ def test_sharded_train_and_decode_match_single_device():
         from repro.configs import get_config
         from repro.models import build_model
         from repro.distributed import sharding as shr
+        from repro.launch.mesh import make_mesh
         from repro.training import AdamWConfig, DataConfig, batch_at, \\
             init_opt_state, make_train_step
 
@@ -87,11 +88,11 @@ def test_sharded_train_and_decode_match_single_device():
         # single device reference
         p_ref, _, m_ref = jax.jit(step)(params, opt, batch)
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         pspecs = shr.to_named(mesh, shr.param_pspecs(m, 'train'))
         ospecs = shr.to_named(mesh, shr.opt_pspecs(m, 'train'))
         bspecs = shr.to_named(mesh, shr.data_pspecs(cfg, mesh, 'train', 8))
-        with mesh:
+        with jax.set_mesh(mesh):
             p_sh, o_sh, m_sh = jax.jit(step, in_shardings=(pspecs, ospecs, bspecs),
                                        out_shardings=(pspecs, ospecs, None))(
                 params, opt, batch)
@@ -105,7 +106,7 @@ def test_sharded_train_and_decode_match_single_device():
         tok = jnp.argmax(last, -1).astype(jnp.int32)
         log_ref, _ = m.decode_step(params, tok, cache, 16)
         cspec = shr.to_named(mesh, shr.cache_pspecs(m, mesh, 8, 16))
-        with mesh:
+        with jax.set_mesh(mesh):
             dstep = jax.jit(m.decode_step,
                             in_shardings=(pspecs, shr.to_named(mesh,
                                 shr.data_pspecs(cfg, mesh, 'decode', 8)), cspec, None),
@@ -130,6 +131,7 @@ def test_elastic_reshard_roundtrip():
         from repro.models import build_model
         from repro.distributed import sharding as shr
         from repro.distributed.elastic import replace_on_mesh, validate_divisibility
+        from repro.launch.mesh import make_mesh
         from repro.training import CheckpointManager
 
         cfg = get_config('qwen1.5-0.5b').reduced(num_heads=4, num_kv_heads=4,
@@ -137,8 +139,8 @@ def test_elastic_reshard_roundtrip():
         m = build_model(cfg)
         params = m.init(jax.random.PRNGKey(0))
         pspec = shr.param_pspecs(m, 'train')
-        mesh_a = jax.make_mesh((4, 2), ('data', 'model'))
-        mesh_b = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh_a = make_mesh((4, 2), ('data', 'model'))
+        mesh_b = make_mesh((2, 4), ('data', 'model'))
         placed = replace_on_mesh(params, pspec, mesh_a)
         with tempfile.TemporaryDirectory() as d:
             ck = CheckpointManager(d)
@@ -158,14 +160,12 @@ def test_compressed_psum_under_shard_map():
     code = textwrap.dedent("""
         import json
         import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:              # moved out of experimental in jax 0.5
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         from repro.training.compression import error_feedback_psum
 
-        mesh = jax.make_mesh((8,), ('pod',))
+        mesh = make_mesh((8,), ('pod',))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 4096), jnp.float32)
 
         def f(xl):
@@ -179,3 +179,42 @@ def test_compressed_psum_under_shard_map():
         print(json.dumps({'rel': rel}))
     """)
     assert _run_subprocess(code)["rel"] < 0.02
+
+
+def test_io_channels_on_other_devices_match_one_channel():
+    """Restoration channels pinned to devices other than the live cache's
+    hand their staged bytes over by an explicit move: serving with one
+    channel per device gives the same verified caches and greedy tokens
+    as one channel (unmoved, the pool write refused the mixed devices)."""
+    code = textwrap.dedent("""
+        import json
+        import jax, numpy as np
+        from repro.launch.serve import build_real_model, real_requests, serve_real
+
+        model, params = build_real_model('qwen3-8b')
+        runs = {}
+        for ch in (4, 1):
+            reqs = real_requests(4, prefix_len=128, new_len=8, decode_len=2,
+                                 id_prefix=f'c{ch}-')
+            out, rep, eng = serve_real(model, params, reqs, io_channels=ch,
+                                       chunk_size=16)
+            ex = eng.executor
+            runs[ch] = dict(
+                moves=out['datapath']['device_moves'],
+                verified=len(rep.restore_secs),
+                tokens=[ex.outputs(r.request_id)['tokens'] for r in reqs],
+                caches=[{f: np.asarray(a) for f, a in
+                         ex.live_cache(r.request_id).items()} for r in reqs])
+        same = all(np.array_equal(a[f], b[f])
+                   for a, b in zip(runs[4]['caches'], runs[1]['caches'])
+                   for f in a)
+        print(json.dumps({'moves4': runs[4]['moves'],
+                          'moves1': runs[1]['moves'],
+                          'verified': [runs[4]['verified'], runs[1]['verified']],
+                          'same_tokens': runs[4]['tokens'] == runs[1]['tokens'],
+                          'same_caches': same}))
+    """)
+    r = _run_subprocess(code)
+    assert r["moves4"] > 0 and r["moves1"] == 0
+    assert r["verified"] == [4, 4]
+    assert r["same_tokens"] and r["same_caches"]

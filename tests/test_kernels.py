@@ -226,18 +226,21 @@ def test_kv_restore_raw_copy_bit_exact(dtype):
 
 def test_kv_restore_slot_subspan():
     """A layer span owning only slots [lo, hi) must leave other slots'
-    rows untouched (multi-stage splits restore sub-spans)."""
+    rows untouched (multi-stage splits restore sub-spans) — in the kernel
+    too, whose grid covers the sub-span only."""
     from repro.kernels.kv_restore import ops as kr_ops
-    a, s, c, cs = 4, 16, 64, 8
+    a, s, c, cs = 4, 16, 128, 8
     cache = jax.random.normal(jax.random.fold_in(RNG, 3), (a, s, c))
     staged = jax.random.normal(jax.random.fold_in(RNG, 4), (a, cs, c))
-    out = kr_ops.kv_restore_scatter([cache], [staged], None, t0=8,
-                                    slot_lo=1, n_slots=2, chunk_size=cs,
-                                    backend="ref")[0]
-    o, ca, st = (np.asarray(x) for x in (out, cache, staged))
-    np.testing.assert_array_equal(o[0], ca[0])
-    np.testing.assert_array_equal(o[3], ca[3])
-    np.testing.assert_array_equal(o[1:3, 8:16], st[1:3])
+    for backend in ("interpret", "ref"):
+        out = kr_ops.kv_restore_scatter([cache], [staged], None, t0=8,
+                                        slot_lo=1, n_slots=2, chunk_size=cs,
+                                        backend=backend)[0]
+        o, ca, st = (np.asarray(x) for x in (out, cache, staged))
+        np.testing.assert_array_equal(o[0], ca[0])
+        np.testing.assert_array_equal(o[3], ca[3])
+        np.testing.assert_array_equal(o[1:3, :8], ca[1:3, :8])
+        np.testing.assert_array_equal(o[1:3, 8:16], st[1:3])
 
 
 def test_kv_restore_dequant_matches_kv_dequantize():

@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.cost_model import CostModel
 from repro.core.plans import TwoPointerPlan, make_request_plans

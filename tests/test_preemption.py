@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 from _engine_helpers import RngBackend
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.config import HARDWARE, IO_BANDWIDTHS
 from repro.configs import get_config

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from _engine_helpers import RngBackend
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.analysis.codelint import (check_at_set_loops,
                                      check_kernel_oracles,

@@ -117,3 +117,27 @@ def test_metrics_helpers():
     assert st["p50"] == pytest.approx(50.5)
     pts = cdf(vals, n_points=11)
     assert pts[0][1] == 0.0 and pts[-1][1] == 1.0
+
+
+@pytest.mark.parametrize("env_set", [True, False], ids=["env", "checkout"])
+def test_compile_cache_location(monkeypatch, env_set):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and the code then sets nothing;
+    otherwise the cache is the checkout's git-ignored ``.jax_cache``."""
+    import os
+    from repro.launch import compile_cache as cc
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        if env_set:
+            monkeypatch.setenv(cc.ENV, "/elsewhere/cache")
+            assert cc.use_compile_cache() == "/elsewhere/cache"
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            monkeypatch.delenv(cc.ENV, raising=False)
+            path = cc.use_compile_cache()
+            assert path == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            with open(os.path.join(root, ".gitignore")) as f:
+                assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

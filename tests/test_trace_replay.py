@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.config import HARDWARE, IO_BANDWIDTHS
 from repro.configs import get_config
