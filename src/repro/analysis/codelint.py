@@ -2,7 +2,7 @@
 
     python -m repro.analysis.codelint [--root PATH] [--json]
 
-Five rules encoding conventions this repo has paid for breaking:
+Six rules encoding conventions this repo has paid for breaking:
 
   * ``kernel-oracle``   — every ``kernels/<name>/kernel.py`` ships a
     ``ref.py`` NumPy/JAX oracle AND an interpret-mode parity test (a test
@@ -29,6 +29,11 @@ Five rules encoding conventions this repo has paid for breaking:
     ``METRIC_CATALOG`` (the registry also enforces this at runtime, but
     telemetry is opt-in, so an unregistered name would otherwise only
     explode in the rare telemetry-on run).
+  * ``span-catalog``    — likewise every span name passed as a string
+    literal to a ``span()`` / ``instant()`` call under ``src/repro`` is
+    declared in ``SPAN_CATALOG`` (the span recorder, ``obs/spans.py``,
+    records whatever it is given; the benchmark's readers find spans by
+    these names).
 
 Each ``check_*`` function takes explicit paths so the mutation self-tests
 can point them at synthetic files.
@@ -279,19 +284,22 @@ def check_trace_kinds(trace_py: Path) -> List[CodeLintFinding]:
 #: metric name (the scan keys on the METHOD name, so any registry-shaped
 #: object — MetricsRegistry or a future facade — is covered)
 METRIC_METHODS = {"counter", "gauge", "histogram"}
+#: span-recorder entry points whose first positional string argument is a
+#: span name (``obs/spans.py``; called bare or as a module attribute)
+SPAN_FUNCTIONS = {"span", "instant"}
 
 
-def _catalog_names(registry_py: Path):
-    """Parse the ``METRIC_CATALOG`` literal dict's keys, or None if the
+def _catalog_names(registry_py: Path, catalog: str = "METRIC_CATALOG"):
+    """Parse the ``catalog`` literal dict's keys, or None if the
     assignment is missing/not a literal (mirrors EVENT_KINDS handling)."""
     tree = ast.parse(registry_py.read_text(), filename=str(registry_py))
     for node in ast.walk(tree):
         if isinstance(node, ast.AnnAssign) and \
                 isinstance(node.target, ast.Name) and \
-                node.target.id == "METRIC_CATALOG" and node.value is not None:
+                node.target.id == catalog and node.value is not None:
             value = node.value
         elif isinstance(node, ast.Assign) and any(
-                isinstance(t, ast.Name) and t.id == "METRIC_CATALOG"
+                isinstance(t, ast.Name) and t.id == catalog
                 for t in node.targets):
             value = node.value
         else:
@@ -313,27 +321,50 @@ def check_metric_catalog(registry_py: Path,
             "metric-catalog", str(registry_py), 1,
             "no METRIC_CATALOG literal dict found — the metric catalog "
             "is gone")]
-    out: List[CodeLintFinding] = []
+    return [CodeLintFinding(
+        "metric-catalog", str(path), line,
+        f"metric {name!r} used at a .{func}() call but not registered in "
+        f"METRIC_CATALOG (obs/registry.py)")
+        for path, line, func, name in _literal_calls(paths, METRIC_METHODS,
+                                                     bare=False)
+        if name not in registered]
+
+
+def check_span_catalog(registry_py: Path,
+                       paths: Sequence[Path]) -> List[CodeLintFinding]:
+    registered = (_catalog_names(registry_py, "SPAN_CATALOG")
+                  if registry_py.exists() else None)
+    if registered is None:
+        return [CodeLintFinding(
+            "span-catalog", str(registry_py), 1,
+            "no SPAN_CATALOG literal dict found — the span catalog is gone")]
+    return [CodeLintFinding(
+        "span-catalog", str(path), line,
+        f"span {name!r} used at a {func}() call but not declared in "
+        f"SPAN_CATALOG (obs/registry.py)")
+        for path, line, func, name in _literal_calls(paths, SPAN_FUNCTIONS,
+                                                     bare=True)
+        if name not in registered]
+
+
+def _literal_calls(paths: Sequence[Path], funcs, *, bare: bool):
+    """``(path, line, function, literal)`` of every call to one of
+    ``funcs`` — as an attribute, and with ``bare`` as a plain name too —
+    whose first positional argument is a string literal."""
     for path in paths:
         if not path.exists():
             continue
         tree = ast.parse(path.read_text(), filename=str(path))
         for node in ast.walk(tree):
-            if not (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in METRIC_METHODS
-                    and node.args
+            if not (isinstance(node, ast.Call) and node.args
                     and isinstance(node.args[0], ast.Constant)
                     and isinstance(node.args[0].value, str)):
                 continue
-            name = node.args[0].value
-            if name not in registered:
-                out.append(CodeLintFinding(
-                    "metric-catalog", str(path), node.lineno,
-                    f"metric {name!r} used at a .{node.func.attr}() call "
-                    f"but not registered in METRIC_CATALOG "
-                    f"(obs/registry.py)"))
-    return out
+            f = node.func
+            name = (f.attr if isinstance(f, ast.Attribute) else
+                    f.id if bare and isinstance(f, ast.Name) else None)
+            if name in funcs:
+                yield path, node.lineno, name, node.args[0].value
 
 
 # ---------------------------------------------------------------------------
@@ -353,6 +384,8 @@ def run_all(root: Path) -> List[CodeLintFinding]:
     findings += check_trace_kinds(src / "core" / "trace.py")
     findings += check_metric_catalog(src / "obs" / "registry.py",
                                      sorted(src.rglob("*.py")))
+    findings += check_span_catalog(src / "obs" / "registry.py",
+                                   sorted(src.rglob("*.py")))
     return findings
 
 

@@ -57,6 +57,7 @@ import numpy as np
 
 from repro.kernels.kv_restore import kv_restore_scatter
 from repro.kernels.kv_restore.ops import pick_backend
+from repro.obs.spans import span
 
 ATTN_FIELDS = ("k", "v", "ckv")
 
@@ -141,6 +142,7 @@ class RestoreDatapath:
         self.runs = 0
         self.ops = 0
         self.last_op_dispatches = 0    # copy dispatches of the latest op
+        self.last_op_bytes = 0         # wire bytes staged by the latest op
         self._last_secs: Optional[float] = None
 
     @classmethod
@@ -171,6 +173,13 @@ class RestoreDatapath:
         (mutated in place and returned).  ``packed`` is the op's
         ``fetch_range_packed`` result; ``slot_span`` the contiguous
         attention-slot range the op's layer span owns."""
+        with span("load.scatter") as sp:
+            self._restore_op(cache, packed, store, slot_span, channel)
+            sp.set(bytes_staged=self.last_op_bytes)
+        return cache
+
+    def _restore_op(self, cache: dict, packed, store, slot_span,
+                    channel: int):
         fields = [f for f in ATTN_FIELDS if f in cache]
         s_lo, s_hi = slot_span
         cs = store.chunk_size
@@ -234,13 +243,13 @@ class RestoreDatapath:
 
         self.ops += 1
         self.last_op_dispatches = dispatches
+        self.last_op_bytes = moved
         if self.measure:
             jax.block_until_ready([cache[f] for f in fields]
                                   + [cache["kpos"]])
             secs = time.perf_counter() - t_begin
             stream.note(secs, moved)
             self._last_secs = secs
-        return cache
 
     def _to_cache_device(self, dev: dict, cache: dict, nbytes: int) -> dict:
         """Staged buffers land on their channel's device; the scatter runs

@@ -648,7 +648,7 @@ class EngineCore:
         self.last_sanitizer = san
         tel = None
         if self.telemetry:
-            # lazy import, same as the sanitizer: repro.obs never loads on
+            # lazy import, same as the sanitizer: no Telemetry is built on
             # the default (telemetry off) path
             from repro.obs.telemetry import Telemetry
             tel = self.telemetry if isinstance(self.telemetry, Telemetry) \

@@ -27,6 +27,7 @@ from repro.core.scheduler import ScheduledOp
 from repro.models.kvcache import (PagedKVCache, grow_cache, park_cache,
                                   unpark_cache)
 from repro.models.model import Model
+from repro.obs.spans import instant, span
 
 ATTN_FIELDS = ("k", "v", "ckv")
 
@@ -70,6 +71,9 @@ class RestorationExecutor:
         self.load_dispatches = 0
         self.fused_loads = 0
         self.legacy_loads = 0
+        # load ops whose attention KV came from the device reference copy
+        # (no chunk store, or the store could not serve the op's chunks)
+        self.reference_restores = 0
         # live restoration state: rid -> dict(cache=..., act={stage: x}, ...)
         self._live: Dict[str, dict] = {}
         # lifecycle inputs registered before the engine runs:
@@ -223,12 +227,21 @@ class RestorationExecutor:
                 "step_logits": list(live.get("step_logits", []))}
 
     def execute_op(self, op: ScheduledOp):
+        rid, (t0, t1), (lo, hi) = op.request_id, op.tokens, op.layers
         if op.kind == "compute":
-            self._exec_compute(op)
+            with span("restore.compute", rid, stage=op.stage,
+                      tokens=t1 - t0, layers=hi - lo):
+                self._exec_compute(op)
         elif op.kind == "prefill":
-            self._exec_prefill(op)
+            with span("prefill", rid, tokens=t1 - t0, layers=hi - lo):
+                self._exec_prefill(op)
         else:
-            self._exec_load(op)
+            with span("restore.load", rid, tokens=t1 - t0,
+                      layers=hi - lo) as s:
+                fetches = getattr(self.chunk_store, "fetches", 0)
+                from_reference = self._exec_load(op)
+                s.set(fetched=getattr(self.chunk_store, "fetches", 0)
+                      - fetches, from_reference=from_reference)
 
     # -- compute ---------------------------------------------------------
     def _stage_input(self, rid: str, stage: int, t0: int, t1: int):
@@ -286,7 +299,9 @@ class RestorationExecutor:
         return slots[0], slots[-1] + 1
 
     # -- load --------------------------------------------------------------
-    def _exec_load(self, op: ScheduledOp):
+    def _exec_load(self, op: ScheduledOp) -> bool:
+        """Run one load op; True when its attention KV came from the
+        device reference copy."""
         live = self._live[op.request_id]
         req: StoredRequest = live["req"]
         cache, ref = live["cache"], req.kv_reference
@@ -340,6 +355,7 @@ class RestorationExecutor:
                         self.load_dispatches += 1
                     continue
                 if kp_all is None:
+                    self.reference_restores += 1
                     kp_all = np.asarray(ref["kpos"])
                 # slots whose stored position falls inside [t0, t1)
                 sel = np.nonzero((kp_all[slot] >= t0)
@@ -364,6 +380,7 @@ class RestorationExecutor:
                         for f, arr in snap.items():
                             cache[f] = cache[f].at[slot].set(arr[slot])
         live["cache"] = cache
+        return kp_all is not None
 
     def _map_loaded_blocks(self, rid: str, t0: int, t1: int):
         """After a load fetched tokens [t0, t1), alias the now-HBM-resident
@@ -390,62 +407,82 @@ class RestorationExecutor:
         positions = jnp.arange(t0, t1, dtype=jnp.int32)[None]
         if "prefill_x" not in live:
             # first stage: make room for suffix + decode tail, embed suffix
-            live["cache"] = grow_cache(m.cfg, live["cache"],
-                                       req.n_tokens + (t1 - t0) + decode_len)
-            live["prefill_x"] = m.embed(self.params, new_inputs, positions)
-        x, cache = m.stack_chunk(self.params, live["prefill_x"], positions,
-                                 live["cache"], lo, hi)
+            with span("prefill.grow"):
+                live["cache"] = grow_cache(
+                    m.cfg, live["cache"],
+                    req.n_tokens + (t1 - t0) + decode_len)
+                live["prefill_x"] = m.embed(self.params, new_inputs,
+                                            positions)
+        with span("prefill.forward"):
+            x, cache = m.stack_chunk(self.params, live["prefill_x"],
+                                     positions, live["cache"], lo, hi)
         live["prefill_x"], live["cache"] = x, cache
         if hi == m.cfg.num_layers:
             # last pipeline stage: the suffix's final activation gives the
             # request's FIRST output token
             logits = m.unembed(self.params, x[:, -1:])[:, 0]
+            with span("prefill.readback"):
+                token = int(jnp.argmax(logits[0]))
+            instant("token", op.request_id, index=0)
             live["first_logits"] = logits
             live["last_logits"] = logits
-            live["tokens_out"] = [int(jnp.argmax(logits[0]))]
+            live["tokens_out"] = [token]
             live["step_logits"] = []
             live["pos"] = t1
             # every layer's suffix KV is now in the contiguous cache:
             # append it through the block table (CoW against forks)
             if "kpos" in live["cache"]:
-                self._paged_write(live, t0, t1)
+                with span("prefill.paged_write"):
+                    self._paged_write(live, t0, t1)
 
     # -- batched decode (one token per request per step) -------------------
     def decode_step_batch(self, rids: List[str]):
         """One engine decode step: append one generated token to every
         listed request's live cache (greedy feed of its previous output)."""
+        with span("decode.step", rids=list(rids), batch=len(rids)):
+            for rid in rids:
+                live = self._live[rid]
+                with span("decode.token", rid, position=live.get("pos")):
+                    self._decode_token(rid, live)
+
+    def _decode_token(self, rid: str, live: dict):
         m, cfg = self.model, self.model.cfg
-        for rid in rids:
-            live = self._live[rid]
-            req: StoredRequest = live["req"]
-            if "pos" not in live:
-                # decode-only lifecycle (no suffix): seed from the stored
-                # prefix's final logits and grow room for the decode tail
-                _, decode_len = self._suffix.get(rid, (None, 0))
-                live["cache"] = grow_cache(cfg, live["cache"],
-                                           req.n_tokens + max(1, decode_len))
-                live["last_logits"] = req.final_logits
-                live["tokens_out"] = []
-                live["step_logits"] = []
-                live["pos"] = req.n_tokens
+        req: StoredRequest = live["req"]
+        if "pos" not in live:
+            # decode-only lifecycle (no suffix): seed from the stored
+            # prefix's final logits and grow room for the decode tail
+            _, decode_len = self._suffix.get(rid, (None, 0))
+            live["cache"] = grow_cache(cfg, live["cache"],
+                                       req.n_tokens + max(1, decode_len))
+            live["last_logits"] = req.final_logits
+            live["tokens_out"] = []
+            live["step_logits"] = []
+            live["pos"] = req.n_tokens
+        with span("decode.input"):
             if cfg.input_mode == "tokens":
-                inp = jnp.argmax(live["last_logits"], axis=-1).astype(jnp.int32)
+                inp = jnp.argmax(live["last_logits"],
+                                 axis=-1).astype(jnp.int32)
             else:
                 # embedding frontends have no token feedback path; feed a
                 # deterministic pseudo-embedding keyed on the position
                 key = jax.random.fold_in(jax.random.PRNGKey(0), live["pos"])
                 inp = jax.random.normal(key, (1, cfg.d_model), jnp.float32)
-            logits, cache = m.decode_step_jit(self.params, inp, live["cache"],
-                                              live["pos"])
-            live["cache"] = cache
-            live["last_logits"] = logits
-            if "kpos" in cache:
-                # append the new token's KV through the block table: a tail
-                # block shared with a forked sibling copies here (CoW)
+        with span("decode.forward"):
+            logits, cache = m.decode_step_jit(self.params, inp,
+                                              live["cache"], live["pos"])
+        live["cache"] = cache
+        live["last_logits"] = logits
+        if "kpos" in cache:
+            # append the new token's KV through the block table: a tail
+            # block shared with a forked sibling copies here (CoW)
+            with span("decode.paged_write"):
                 self._paged_write(live, live["pos"], live["pos"] + 1)
-            live["pos"] += 1
-            live["tokens_out"].append(int(jnp.argmax(logits[0])))
-            live["step_logits"].append(logits)
+        live["pos"] += 1
+        with span("decode.readback"):
+            token = int(jnp.argmax(logits[0]))
+        live["tokens_out"].append(token)
+        instant("token", rid, index=len(live["tokens_out"]) - 1)
+        live["step_logits"].append(logits)
 
     # ------------------------------------------------------------------
     def restore(self, rid: str, *, l_delta: int = 0, strategy: Optional[str] = None,

@@ -1,8 +1,8 @@
 """Engine-wide metrics registry (DESIGN.md §15).
 
-Three primitive types, all driven by the ENGINE clock (virtual seconds in
-sim, measured wall seconds in real mode — whatever ``EngineCore.run``'s
-``now`` is):
+Three primitive types, stamped by their writer's clock (``Telemetry``:
+engine-clock virtual seconds in sim, the host's span clock in measured
+real mode):
 
   * :class:`Counter`   — monotone non-decreasing accumulator.
   * :class:`Gauge`     — last-value sample; when a timestamp is supplied the
@@ -17,6 +17,9 @@ label schema and owning layer — ``analysis/codelint.py`` statically checks
 that every metric-name literal in the codebase is registered here (the same
 pattern as the ``EVENT_KINDS`` trace-schema rule), and the registry enforces
 the type and exact label keys at instantiation time.
+
+:data:`SPAN_CATALOG` does the same for the names of the in-program spans
+(``obs/spans.py``).
 """
 from __future__ import annotations
 
@@ -82,6 +85,10 @@ METRIC_CATALOG = {
         "type": "counter", "labels": (), "layer": "core/datapath"},
     "datapath.oracle_runs_total": {
         "type": "counter", "labels": (), "layer": "core/datapath"},
+    # load ops whose bytes came from the device reference copy because the
+    # chunk store could not serve them (core/executor.py)
+    "datapath.reference_restores_total": {
+        "type": "counter", "labels": (), "layer": "core/executor"},
     # ---- storage tiers (storage/placement.py, storage/chunkstore.py) ----
     "storage.tier_used_bytes": {
         "type": "gauge", "labels": ("tier",), "layer": "storage/placement"},
@@ -92,6 +99,63 @@ METRIC_CATALOG = {
         "layer": "storage/chunkstore"},
     "storage.bytes_total": {
         "type": "counter", "labels": ("op",), "layer": "storage/chunkstore"},
+}
+
+#: The span catalog (``obs/spans.py``): name -> {layer, read_by}.  ``layer``
+#: is the module the span sits in; ``read_by`` the benchmark metric (or
+#: observable) that reads it.  Idle device time under a span is attributed
+#: by ``bench/harness/program_spans.py``.  A PURE LITERAL, like
+#: METRIC_CATALOG: codelint checks every span-name literal against it.
+SPAN_CATALOG = {
+    "engine.run": {
+        "layer": "serving/engine",
+        "read_by": "idle_by_program_span (engine self time)"},
+    "restore.compute": {
+        "layer": "core/executor", "read_by": "idle_by_program_span"},
+    "restore.load": {
+        "layer": "core/executor", "read_by": "idle_by_program_span"},
+    "load.fetch": {
+        "layer": "storage/chunkstore", "read_by": "idle_by_program_span"},
+    "load.scatter": {
+        "layer": "core/datapath", "read_by": "idle_by_program_span"},
+    "prefill": {
+        "layer": "core/executor", "read_by": "idle_by_program_span"},
+    "prefill.grow": {
+        "layer": "core/executor", "read_by": "idle_by_program_span"},
+    "prefill.forward": {
+        "layer": "core/executor", "read_by": "idle_by_program_span"},
+    "prefill.readback": {
+        "layer": "core/executor", "read_by": "idle_by_program_span"},
+    "prefill.paged_write": {
+        "layer": "core/executor", "read_by": "idle_by_program_span"},
+    "decode.step": {
+        "layer": "core/executor",
+        "read_by": "device_idle_share.decode_dispatch, "
+                   "decode_programs_per_token"},
+    "decode.token": {
+        "layer": "core/executor",
+        "read_by": "device_idle_share.decode_dispatch"},
+    "decode.input": {
+        "layer": "core/executor",
+        "read_by": "device_idle_share.decode_dispatch"},
+    "decode.forward": {
+        "layer": "core/executor",
+        "read_by": "device_idle_share.decode_dispatch"},
+    "decode.paged_write": {
+        "layer": "core/executor",
+        "read_by": "device_idle_share.decode_dispatch"},
+    "decode.readback": {
+        "layer": "core/executor",
+        "read_by": "device_idle_share.decode_readback"},
+    "kv_quant": {
+        "layer": "storage/chunkstore", "read_by": "idle_by_program_span"},
+    "compile": {
+        "layer": "obs/spans (jax.monitoring)",
+        "read_by": "device_idle_share.compiling"},
+    # instant: one host-clock stamp per generated token (rid, index)
+    "token": {
+        "layer": "core/executor",
+        "read_by": "Telemetry.snapshot()['spans'] (serve --metrics-out)"},
 }
 
 
@@ -120,7 +184,7 @@ class Counter:
 class Gauge:
     """Last-value sample; ``set(v, t=...)`` additionally appends to the
     gauge's ``(t, value)`` series (the timeline exporter's counter-track
-    source).  Timestamps are engine-clock seconds."""
+    source).  Timestamps are the writer's clock, in seconds."""
 
     def __init__(self, name: str):
         self.name = name

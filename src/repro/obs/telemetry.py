@@ -9,8 +9,12 @@ mutate it, so a telemetry-enabled run is bit-identical to a disabled one
 on ``EngineResult`` and ``ops_log`` (property-tested in
 ``tests/test_obs.py``).
 
-What it collects, on the engine clock (virtual seconds in sim, measured
-wall seconds in real mode):
+What it collects, on the engine clock in simulation (virtual seconds) and
+on the host's span clock in measured real mode (``RealBackend`` without a
+``dur_fn``), where the engine clock is a resource model that assumes
+overlap the serial host never gives.  Host-clock stamps are seconds since
+the run began (``clock["t0_ns"]`` in the snapshot, on
+``time.perf_counter_ns``), read by the hooks themselves:
 
   * queue depth / active batch size as ``(t, value)`` series,
   * admitted- and decode-batch-size histograms,
@@ -25,21 +29,16 @@ wall seconds in real mode):
 
 ``snapshot()`` is the exposition API: a plain-JSON dict carried by
 ``ServingReport.telemetry``, written by ``serve --metrics-out`` and
-consumed by the benchmarks.
+consumed by the benchmarks.  It also carries the spans
+(``obs/spans.py``) recorded during the run.
 """
 from __future__ import annotations
 
-import os
 from typing import Dict, List, Optional
 
+from repro.obs import spans
 from repro.obs.registry import MetricsRegistry
-
-
-def telemetry_env_enabled() -> bool:
-    """The ``CACHEFLOW_TELEMETRY`` opt-in, same convention as the
-    sanitizer's ``CACHEFLOW_SANITIZE``."""
-    return os.environ.get(
-        "CACHEFLOW_TELEMETRY", "0").lower() not in ("", "0", "false")
+from repro.obs.spans import telemetry_env_enabled  # noqa: F401
 
 
 class Telemetry:
@@ -56,10 +55,24 @@ class Telemetry:
         self.phases: Dict[str, List[list]] = {}
         self._arrival: Dict[str, float] = {}
         self._admit_t: Dict[str, float] = {}
+        self._t0_ns: Optional[int] = None     # host clock: run start
+        self._spans = (0, 0)                  # the run's span-log slice
 
     # ------------------------------------------------------------------
     def begin(self, core) -> None:
         self.core = core
+        backend = getattr(core, "backend", None)
+        if hasattr(backend, "executor") and \
+                getattr(backend, "dur_fn", True) is None:
+            self._t0_ns = spans.now_ns()
+        self._spans = (spans.log().mark(), None)
+
+    def _clock(self, now: float) -> float:
+        """The hooks' timestamp: the engine clock, or in measured real
+        mode the host's span clock."""
+        if self._t0_ns is None:
+            return now
+        return (spans.now_ns() - self._t0_ns) * 1e-9
 
     def _phase(self, now: float, rid: str, phase: str) -> None:
         self.phases.setdefault(rid, []).append([now, phase])
@@ -84,12 +97,14 @@ class Telemetry:
     # ---- engine hooks (every call site is behind `if tel is not None`) --
     def on_arrive(self, now: float, rid: str, *, queued: int,
                   active: int) -> None:
+        now = self._clock(now)
         self._arrival[rid] = now
         self._phase(now, rid, "arrive")
         self._sample_queues(now, queued, active)
 
     def on_admit(self, now: float, rid: str, *, queued: int,
                  active: int) -> None:
+        now = self._clock(now)
         self._admit_t[rid] = now
         self._phase(now, rid, "admit")
         self.registry.counter("engine.admissions_total").inc()
@@ -123,6 +138,7 @@ class Telemetry:
 
     def on_preempt(self, now: float, rid: str, *, evict: bool,
                    aborted_ops: int) -> None:
+        now = self._clock(now)
         self.registry.counter(
             "engine.preemptions_total",
             mode="evict" if evict else "park").inc()
@@ -135,9 +151,11 @@ class Telemetry:
         self._sample_tiers(now)
 
     def on_resume(self, now: float, rid: str) -> None:
+        now = self._clock(now)
         self._phase(now, rid, "resume")
 
     def on_restore_done(self, now: float, rid: str) -> None:
+        now = self._clock(now)
         self._phase(now, rid, "restored")
         start = self._admit_t.get(rid)
         if start is not None:
@@ -146,6 +164,7 @@ class Telemetry:
         self._sample_tiers(now)
 
     def on_first_token(self, now: float, rid: str) -> None:
+        now = self._clock(now)
         self._phase(now, rid, "first_token")
         arr = self._arrival.get(rid)
         if arr is not None:
@@ -153,6 +172,7 @@ class Telemetry:
 
     def on_finish(self, now: float, rid: str, *, queued: int,
                   active: int) -> None:
+        now = self._clock(now)
         self._phase(now, rid, "finish")
         self._sample_queues(now, queued, active)
         self._sample_tiers(now)
@@ -173,13 +193,18 @@ class Telemetry:
                 resource=resource).set(busy[resource])
         self._sweep_datapath()
         self._sweep_storage()
+        self._spans = (self._spans[0], len(spans.log().records))
 
     def _sweep_datapath(self) -> None:
         """Real mode: the fused datapath's per-channel ``TransferStream``s
         carry measured bytes and seconds — the serve observable behind the
         paper's per-channel bandwidth claims."""
-        dp = getattr(getattr(self.core, "backend", None), "executor", None)
-        dp = getattr(dp, "datapath", None)
+        ex = getattr(getattr(self.core, "backend", None), "executor", None)
+        refs = getattr(ex, "reference_restores", None)
+        if refs is not None:
+            self.registry.counter(
+                "datapath.reference_restores_total").inc(refs)
+        dp = getattr(ex, "datapath", None)
         if dp is None:
             return
         self.registry.counter(
@@ -233,6 +258,10 @@ class Telemetry:
         timestamps.  Carried by ``ServingReport.telemetry``, written by
         ``serve --metrics-out``, consumed by the benchmarks and the
         timeline exporter's counter tracks."""
+        lo, hi = self._spans
         return {"metrics": self.registry.snapshot(),
                 "phases": {rid: [list(p) for p in edges]
-                           for rid, edges in sorted(self.phases.items())}}
+                           for rid, edges in sorted(self.phases.items())},
+                "clock": ({"kind": "engine"} if self._t0_ns is None
+                          else {"kind": "host", "t0_ns": self._t0_ns}),
+                "spans": spans.export(lo, hi) if hi is not None else []}

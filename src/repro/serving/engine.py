@@ -37,6 +37,7 @@ from repro.core.engine_core import (EngineCore, EngineRequest, EngineResult,
                                     RealBackend, SimBackend,
                                     interleaving_dur_fn)
 from repro.core.executor import RestorationExecutor
+from repro.obs import spans
 from repro.serving.kvstore import TieredKVStore
 from repro.serving.metrics import lifecycle_stats, percentiles
 from repro.serving.request import Phase, Request
@@ -360,9 +361,17 @@ class RealServingEngine:
                           admission=self.admission, prefetch=self.prefetch,
                           sanitize=self.sanitize, telemetry=self.telemetry,
                           strict=True)
-        t0 = time.perf_counter()
-        res = core.run(engine_reqs, trace=trace)
-        serve_wall = time.perf_counter() - t0
+        # telemetry records the run's spans too (the CACHEFLOW_TELEMETRY
+        # opt-in already does; a per-engine opt-in does so for this run)
+        was = spans.force_recording(True) if self.telemetry else None
+        try:
+            t0 = time.perf_counter()
+            with spans.span("engine.run", requests=len(engine_reqs)):
+                res = core.run(engine_reqs, trace=trace)
+            serve_wall = time.perf_counter() - t0
+        finally:
+            if was is not None:
+                spans.force_recording(was)
         san = core.last_sanitizer
         tel = core.last_telemetry
         ttfts, restore_secs, e2e, tpots, total, arrivals, finishes = \

@@ -69,6 +69,7 @@ import numpy as np
 from repro.kernels.kv_quant import kv_dequantize, kv_quantize
 from repro.kernels.kv_quant.ops import resolve_backend
 from repro.models.kvcache import BlockPool
+from repro.obs.spans import span
 from repro.storage.placement import PlacementCore, Tier
 
 CHUNK_TIERS = ("hbm", "host", "disk")
@@ -252,13 +253,17 @@ class ChunkStore:
 
     def _quantize(self, raw: dict) -> dict:
         out = {"kpos": raw["kpos"]}
-        for f, arr in raw.items():
-            if f == "kpos":
-                continue
-            q, scales = kv_quantize(jnp.asarray(arr),
-                                    backend=self._quant_backend())
-            self.max_scale = max(self.max_scale, float(jnp.max(scales)))
-            out[f] = {"q": np.asarray(q), "scales": np.asarray(scales)}
+        rows = sum(int(np.prod(a.shape[:-1])) for f, a in raw.items()
+                   if f != "kpos")
+        with span("kv_quant", rows=rows):
+            for f, arr in raw.items():
+                if f == "kpos":
+                    continue
+                q, scales = kv_quantize(jnp.asarray(arr),
+                                        backend=self._quant_backend())
+                self.max_scale = max(self.max_scale,
+                                     float(jnp.max(scales)))
+                out[f] = {"q": np.asarray(q), "scales": np.asarray(scales)}
         return out
 
     def _quant_backend(self) -> str:
@@ -501,12 +506,13 @@ class ChunkStore:
             return None
         cs = self.chunk_size
         out = []
-        for ci in range(t0 // cs, min(len(keys), -(-t1 // cs))):
-            got = self.fetch_packed(keys[ci])
-            if got is None:
-                return None
-            c0, c1 = self.chunks[keys[ci]].tokens
-            out.append((c0, c1, got[0], got[1], keys[ci]))
+        with span("load.fetch"):
+            for ci in range(t0 // cs, min(len(keys), -(-t1 // cs))):
+                got = self.fetch_packed(keys[ci])
+                if got is None:
+                    return None
+                c0, c1 = self.chunks[keys[ci]].tokens
+                out.append((c0, c1, got[0], got[1], keys[ci]))
         return out
 
     def promote_staged(self, key: str, dev: dict) -> Optional[str]:
