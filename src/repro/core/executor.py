@@ -10,11 +10,18 @@ the full-prefill ground truth.  The simulator measures the schedule; this
 executor proves its *correctness* (restored KV ≡ recomputed KV for any
 legal op interleaving — a property test randomises the interleaving).
 
-Requests are single-sequence (B = 1) as in the serving engine; decode
-batches across requests by stepping each live cache in arrival order.
+Requests are single-sequence (B = 1) as in the serving engine.  A decode
+step launches, per listed request in order, one forward program that
+donates the request's cache and keeps the greedy next token on the
+device, and one write of the new token's KV into its pool block that
+donates the pool's slabs, so nothing is copied.  Only after every program
+is launched does the step read the tokens back, one pass in the same
+order.  The weights still stream once per request; one forward for the
+whole batch is the next step.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -24,12 +31,31 @@ import numpy as np
 from repro.core.boundary import BoundaryStore, StoredRequest, stage_bounds
 from repro.core.plans import RequestPlan, make_request_plans
 from repro.core.scheduler import ScheduledOp
-from repro.models.kvcache import (PagedKVCache, grow_cache, park_cache,
-                                  unpark_cache)
+from repro.models.kvcache import (PagedKVCache, committed, grow_cache,
+                                  park_cache, token_fields, unpark_cache)
 from repro.models.model import Model
 from repro.obs.spans import instant, span
 
 ATTN_FIELDS = ("k", "v", "ckv")
+
+
+@partial(jax.jit, static_argnames=("step", "embed_dim"),
+         donate_argnames=("cache",))
+def _decode_program(params, token, cache, pos, *, step, embed_dim):
+    """One decode token's forward as one program: the model's decode
+    ``step`` at ``pos`` and the greedy next token.  Returns ``(logits,
+    token', cache', fields)``, ``fields`` the new token's attention fields
+    for the block pool.  Compiles per cache length, not per position or
+    step; ``step`` is static, so a model whose step is replaced gets a
+    program of its own."""
+    if embed_dim:
+        # embedding frontends have no token feedback path; feed a
+        # deterministic pseudo-embedding keyed on the position
+        key = jax.random.fold_in(jax.random.PRNGKey(0), pos)
+        token = jax.random.normal(key, (1, embed_dim), jnp.float32)
+    logits, cache = step(params, token, cache, pos)
+    return (logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), cache,
+            token_fields(cache, pos))
 
 
 class RestorationExecutor:
@@ -69,6 +95,8 @@ class RestorationExecutor:
         # accounting (benchmarks/tests): cache-write + staging dispatches
         # issued by load ops, and which path each load op took
         self.load_dispatches = 0
+        # decode tokens served by the donated programs (_decode_program)
+        self.fused_tokens = 0
         self.fused_loads = 0
         self.legacy_loads = 0
         # load ops whose attention KV came from the device reference copy
@@ -421,8 +449,10 @@ class RestorationExecutor:
             # last pipeline stage: the suffix's final activation gives the
             # request's FIRST output token
             logits = m.unembed(self.params, x[:, -1:])[:, 0]
+            # the first decode token's input stays on the device
+            live["next_token"] = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             with span("prefill.readback"):
-                token = int(jnp.argmax(logits[0]))
+                token = int(np.asarray(live["next_token"])[0])
             instant("token", op.request_id, index=0)
             live["first_logits"] = logits
             live["last_logits"] = logits
@@ -438,14 +468,23 @@ class RestorationExecutor:
     # -- batched decode (one token per request per step) -------------------
     def decode_step_batch(self, rids: List[str]):
         """One engine decode step: append one generated token to every
-        listed request's live cache (greedy feed of its previous output)."""
+        listed request's live cache (greedy feed of its previous output).
+        Every request's program is launched before any token is read."""
         with span("decode.step", rids=list(rids), batch=len(rids)):
-            for rid in rids:
-                live = self._live[rid]
-                with span("decode.token", rid, position=live.get("pos")):
-                    self._decode_token(rid, live)
+            lives = [self._live[rid] for rid in rids]
+            for rid, live in zip(rids, lives):
+                self._launch_token(rid, live)
+            for rid, live in zip(rids, lives):
+                with span("decode.token", rid, position=live["pos"] - 1):
+                    with span("decode.readback"):
+                        token = int(np.asarray(live["next_token"])[0])
+                    live["tokens_out"].append(token)
+                    instant("token", rid, index=len(live["tokens_out"]) - 1)
+                    live["step_logits"].append(live["last_logits"])
 
-    def _decode_token(self, rid: str, live: dict):
+    def _launch_token(self, rid: str, live: dict):
+        """Launch one request's decode program; its token stays on the
+        device until the step reads it back."""
         m, cfg = self.model, self.model.cfg
         req: StoredRequest = live["req"]
         if "pos" not in live:
@@ -455,34 +494,32 @@ class RestorationExecutor:
             live["cache"] = grow_cache(cfg, live["cache"],
                                        req.n_tokens + max(1, decode_len))
             live["last_logits"] = req.final_logits
+            live["next_token"] = jnp.argmax(req.final_logits,
+                                            axis=-1).astype(jnp.int32)
             live["tokens_out"] = []
             live["step_logits"] = []
             live["pos"] = req.n_tokens
-        with span("decode.input"):
-            if cfg.input_mode == "tokens":
-                inp = jnp.argmax(live["last_logits"],
-                                 axis=-1).astype(jnp.int32)
-            else:
-                # embedding frontends have no token feedback path; feed a
-                # deterministic pseudo-embedding keyed on the position
-                key = jax.random.fold_in(jax.random.PRNGKey(0), live["pos"])
-                inp = jax.random.normal(key, (1, cfg.d_model), jnp.float32)
-        with span("decode.forward"):
-            logits, cache = m.decode_step_jit(self.params, inp,
-                                              live["cache"], live["pos"])
-        live["cache"] = cache
-        live["last_logits"] = logits
-        if "kpos" in cache:
-            # append the new token's KV through the block table: a tail
-            # block shared with a forked sibling copies here (CoW)
-            with span("decode.paged_write"):
-                self._paged_write(live, live["pos"], live["pos"] + 1)
-        live["pos"] += 1
-        with span("decode.readback"):
-            token = int(jnp.argmax(logits[0]))
-        live["tokens_out"].append(token)
-        instant("token", rid, index=len(live["tokens_out"]) - 1)
-        live["step_logits"].append(logits)
+        pos = live["pos"]
+        with span("decode.forward", rid, position=pos):
+            token, cache = committed((live["next_token"], live["cache"]))
+            logits, token, cache, fields = _decode_program(
+                self.params, token, cache, pos, step=m.decode_step_jit,
+                embed_dim=0 if cfg.input_mode == "tokens" else cfg.d_model)
+        # the token's copy to the host starts as soon as its program ends,
+        # not when the step's readback asks for it
+        token.copy_to_host_async()
+        live["last_logits"], live["next_token"], live["cache"] = \
+            logits, token, cache
+        paged: Optional[PagedKVCache] = live.get("paged")
+        if paged is not None:
+            # the new token's KV into its block: a fresh block, or a CoW
+            # copy of a tail shared with a fork, then one in-place write
+            with span("decode.paged_write", rid):
+                paged.pool.ensure_layout(fields)
+                bid, off = paged.token_slot(pos)
+                paged.pool.write_token(bid, off, fields)
+        live["pos"] = pos + 1
+        self.fused_tokens += 1
 
     # ------------------------------------------------------------------
     def restore(self, rid: str, *, l_delta: int = 0, strategy: Optional[str] = None,

@@ -29,15 +29,19 @@ session's cache in O(1) copied bytes.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from functools import partial
+from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.config import ModelConfig
 
 # token axis of each attention field inside a per-block payload
-# (k/v/ckv: (n_attn, B, bs, ...); kpos: (n_attn, bs)); +1 in the pool slab
+# (k/v/ckv: (n_attn, B, bs, ...); kpos: (n_attn, bs)) and in a contiguous
+# cache; +1 in the pool slab
 _TOKEN_AXIS = {"k": 2, "v": 2, "ckv": 2, "kpos": 1}
 
 
@@ -250,6 +254,15 @@ class BlockPool:
             idx[_TOKEN_AXIS[f] + 1] = slice(lo, hi)
             self._slabs[f] = self._slabs[f].at[tuple(idx)].set(jnp.asarray(arr))
 
+    def write_token(self, bid: int, off: int, fields: dict):
+        """Write one token's fields (``token_fields``) into slot ``off`` of
+        a SOLELY-OWNED block: one jitted program that donates the slabs,
+        so the write is in place; it compiles once per slab capacity."""
+        assert self.refcounts[bid] == 1, \
+            f"write to shared block {bid} (refcount {self.refcounts[bid]})"
+        slabs, fields = committed((self._slabs, fields))
+        self._slabs = _write_token(slabs, fields, bid, off)
+
     # -- accounting -----------------------------------------------------
     def live_blocks(self) -> int:
         return sum(1 for rc in self.refcounts if rc > 0)
@@ -341,25 +354,38 @@ class PagedKVCache:
         return self.pool.read(self.blocks[idx])
 
     # -- copy-on-write append -------------------------------------------
+    def writable_block(self, idx: int) -> int:
+        """The sole-owner block that logical block ``idx`` writes into: a
+        fresh blank block when unmapped, a copy when shared with another
+        table (refcount > 1) — the writer pays one block copy, every other
+        referent keeps the original bytes."""
+        bid = self.blocks[idx]
+        if bid is None:
+            bid = self.pool.alloc_blank()
+        elif self.pool.refcounts[bid] > 1:
+            new = self.pool.copy(bid)
+            self.pool.decref(bid)
+            bid = new
+        self.blocks[idx] = bid
+        return bid
+
+    def token_slot(self, pos: int) -> Tuple[int, int]:
+        """``(block id, offset)`` that token ``pos`` is written to: the
+        table extended to hold it, its block made writable."""
+        self._extend(pos + 1)
+        bs = self.pool.block_size
+        return self.writable_block(pos // bs), pos % bs
+
     def write_span(self, t0: int, t1: int, fields: dict):
         """Write tokens [t0, t1) of the given attention fields through the
-        table.  Unmapped blocks allocate fresh; blocks shared with another
-        table (refcount > 1) are copied first — the writer pays one block
-        copy, every other referent keeps the original bytes."""
+        table, each block through :meth:`writable_block` (copy-on-write)."""
         self.pool.ensure_layout(fields)
         self._extend(t1)
         bs = self.pool.block_size
         for idx in range(t0 // bs, self._nblocks(t1)):
             lo = max(t0, idx * bs) - idx * bs
             hi = min(t1, (idx + 1) * bs) - idx * bs
-            bid = self.blocks[idx]
-            if bid is None:
-                bid = self.pool.alloc_blank()
-            elif self.pool.refcounts[bid] > 1:
-                new = self.pool.copy(bid)
-                self.pool.decref(bid)
-                bid = new
-            self.blocks[idx] = bid
+            bid = self.writable_block(idx)
             sliced = {}
             for f, arr in fields.items():
                 ax = _TOKEN_AXIS[f]
@@ -367,6 +393,36 @@ class PagedKVCache:
                 take[ax] = slice(idx * bs + lo - t0, idx * bs + hi - t0)
                 sliced[f] = jnp.asarray(arr)[tuple(take)]
             self.pool.write_slice(bid, lo, hi, sliced)
+
+
+def token_fields(cache: dict, pos) -> dict:
+    """Traceable: token ``pos`` of a contiguous cache's attention fields,
+    each with a token axis of length 1 (what ``BlockPool.write_token``
+    takes)."""
+    return {f: lax.dynamic_slice_in_dim(cache[f], pos, 1, axis=ax)
+            for f, ax in _TOKEN_AXIS.items() if f in cache}
+
+
+@partial(jax.jit, donate_argnums=0)
+def _write_token(slabs: dict, fields: dict, bid, off) -> dict:
+    out = {}
+    for f, slab in slabs.items():
+        start = [bid] + [0] * (slab.ndim - 1)
+        start[_TOKEN_AXIS[f] + 1] = off
+        out[f] = lax.dynamic_update_slice(
+            slab, fields[f][None].astype(slab.dtype), start)
+    return out
+
+
+def committed(tree):
+    """``tree`` with every array committed to the device it is on (no
+    copy).  jit compiles committed and uncommitted inputs apart, and a
+    program's outputs are committed once any input is: a program fed
+    both would compile twice per shape."""
+    if all(a.committed for a in jax.tree.leaves(tree)):
+        return tree
+    return jax.tree.map(
+        lambda a: a if a.committed else jax.device_put(a, a.sharding), tree)
 
 
 def grow_cache(cfg: ModelConfig, cache: dict, new_len: int) -> dict:

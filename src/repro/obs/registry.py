@@ -89,6 +89,10 @@ METRIC_CATALOG = {
     # chunk store could not serve them (core/executor.py)
     "datapath.reference_restores_total": {
         "type": "counter", "labels": (), "layer": "core/executor"},
+    # decode tokens served by the donated programs: forward and argmax,
+    # then the pool write (core/executor.py)
+    "decode.fused_tokens_total": {
+        "type": "counter", "labels": (), "layer": "core/executor"},
     # ---- storage tiers (storage/placement.py, storage/chunkstore.py) ----
     "storage.tier_used_bytes": {
         "type": "gauge", "labels": ("tier",), "layer": "storage/placement"},
@@ -132,15 +136,17 @@ SPAN_CATALOG = {
         "layer": "core/executor",
         "read_by": "device_idle_share.decode_dispatch, "
                    "decode_programs_per_token"},
+    # one token's host side after every program of the step is launched:
+    # its readback, then the token's bookkeeping
     "decode.token": {
         "layer": "core/executor",
         "read_by": "device_idle_share.decode_dispatch"},
-    "decode.input": {
-        "layer": "core/executor",
-        "read_by": "device_idle_share.decode_dispatch"},
+    # the launch of one token's forward program (forward + argmax)
     "decode.forward": {
         "layer": "core/executor",
         "read_by": "device_idle_share.decode_dispatch"},
+    # host block bookkeeping (table extent, a fresh block or a CoW copy)
+    # and the launch of the token's one-program pool write
     "decode.paged_write": {
         "layer": "core/executor",
         "read_by": "device_idle_share.decode_dispatch"},

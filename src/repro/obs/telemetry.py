@@ -204,6 +204,9 @@ class Telemetry:
         if refs is not None:
             self.registry.counter(
                 "datapath.reference_restores_total").inc(refs)
+        fused = getattr(ex, "fused_tokens", None)
+        if fused is not None:
+            self.registry.counter("decode.fused_tokens_total").inc(fused)
         dp = getattr(ex, "datapath", None)
         if dp is None:
             return
