@@ -2,31 +2,12 @@
 
 These are the numerators of the roofline shares and of ``mfu``: what the
 work requires, not what the program happens to move.  A multiply-add is
-two operations.
+two operations.  What one token of a model needs is its family's
+``token_flops`` and ``head_flops`` (``bench/families/``).
 """
 from __future__ import annotations
 
-from harness.arch import Arch
-
-
-def matmul_params_per_layer(a: Arch) -> int:
-    """Weights of one layer's matrix multiplications (q, k, v, o and the
-    gated MLP); norm scales are not multiplied and are left out."""
-    return (a.d_model * a.q_width + 2 * a.d_model * a.kv_width
-            + a.q_width * a.d_model + 3 * a.d_model * a.d_ff)
-
-
-def token_flops(a: Arch, ctx: int) -> float:
-    """One token through every layer, attending to ``ctx`` keys (itself
-    included): the projections and MLP, plus scores and the weighted sum
-    over the context."""
-    per_layer = 2 * matmul_params_per_layer(a) + 4 * a.q_width * ctx
-    return float(a.layers * per_layer)
-
-
-def head_flops(a: Arch) -> float:
-    """Logits of one position over the whole vocabulary."""
-    return float(2 * a.d_model * a.vocab)
+from harness.arch import Arch, family_of
 
 
 def turn_flops(a: Arch, prefix: int, suffix: int, outputs: int) -> float:
@@ -35,19 +16,14 @@ def turn_flops(a: Arch, prefix: int, suffix: int, outputs: int) -> float:
     ``outputs - 1`` decode steps, each with its logits.  Restoring the
     prefix is left out: counting recompute would reward recomputing what
     could be loaded."""
+    fam = family_of(a)
     total = 0.0
     for i in range(suffix):
-        total += token_flops(a, prefix + i + 1)
-    total += head_flops(a)
+        total += fam.token_flops(a, prefix + i + 1)
+    total += fam.head_flops(a)
     for k in range(1, outputs):
-        total += token_flops(a, prefix + suffix + k) + head_flops(a)
+        total += fam.token_flops(a, prefix + suffix + k) + fam.head_flops(a)
     return total
-
-
-def decode_flops(a: Arch, prefix: int, suffix: int, outputs: int) -> float:
-    """The decode steps of one turn alone (outputs after the first)."""
-    return sum(token_flops(a, prefix + suffix + k) + head_flops(a)
-               for k in range(1, outputs))
 
 
 def kv_restore_bytes(n_slots: int, rows: int, channels: int,

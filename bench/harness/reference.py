@@ -1,25 +1,22 @@
-"""The plain reference: a float32 forward pass of a dense GQA transformer,
-written from the published equations and nothing of the program.
+"""The plain reference: a float32 forward pass written from the published
+equations and nothing of the program.
 
-    h = RMSNorm(x) · g1
-    q, k, v = h Wq, h Wk, h Wv          (per head; RMSNorm over head_dim on q
-                                          and k where the model has QK-norm)
-    q, k = RoPE(q), RoPE(k)              (rotate-half form, all head dims)
-    x = x + softmax(q kᵀ / √d_head, causal) v Wo
-    h = RMSNorm(x) · g2
-    x = x + (silu(h Wg) ⊙ h Wu) Wd
+    x = E[tokens]
+    x = layers(x)                        (the family's, bench/families/)
     logits = RMSNorm(x) · g_f  E ᵀ  (tied)  or  · U  (untied)
 
 Every multiplication runs at ``Precision.HIGHEST`` (float32 on the TPU's
 matrix unit).  The sequence runs layer by layer (one layer's weights are
 cast to float32 at a time, so the reference fits beside the bfloat16
-weights) and attention in blocks of queries.  Lengths are padded to a
-bucket so that few programs compile; padded tokens come after every real
-one and causality keeps them out of real positions.
+weights) and attention in blocks of ``Q_BLOCK`` queries.  Lengths are
+padded to a bucket so that few programs compile; padded tokens come after
+every real one and causality keeps them out of real positions.
 
 ``mode="fp8"`` is the control: the same pass with every matrix
 multiplication's operands rounded to float8 e4m3 (weights scaled per
-tensor, activations per row), the precision below bfloat16.
+tensor, activations per row), the precision below bfloat16.  The helpers
+here (``mm``, ``fq``, ``rms``, ``rope``) are what the families' layers are
+written with.
 """
 from __future__ import annotations
 
@@ -30,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.arch import Arch
+from harness.arch import Arch, family_of
 
 HI = jax.lax.Precision.HIGHEST
 BUCKET = 1024
@@ -38,25 +35,25 @@ Q_BLOCK = 512
 F8_MAX = 448.0
 
 
-def _fq(x, axis):
+def fq(x, axis):
     """Round to float8 e4m3 with a scale per slice along ``axis``."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / F8_MAX
     s = jnp.where(s > 0, s, 1.0)
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(x, w, fp8: bool):
+def mm(x, w, fp8: bool):
     """x (..., K) @ w (K, N), both float32."""
     if fp8:
-        x, w = _fq(x, -1), _fq(w, None)
+        x, w = fq(x, -1), fq(w, None)
     return jnp.matmul(x, w, precision=HI)
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (T, H, Dh); positions (T,)."""
     dh = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
@@ -66,50 +63,6 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-@functools.partial(jax.jit, static_argnames=("a", "fp8"))
-def _layer(layers, li, x, pos, *, a: Arch, fp8: bool):
-    """One layer over a padded sequence x (T, D) float32."""
-    w = jax.tree.map(lambda t: t[li].astype(jnp.float32), layers)
-    t = x.shape[0]
-    g = a.heads // a.kv_heads
-    h = _rms(x, w["norm1"], a.norm_eps)
-    k = _mm(h, w["wk"], fp8).reshape(t, a.kv_heads, a.head_dim)
-    v = _mm(h, w["wv"], fp8).reshape(t, a.kv_heads, a.head_dim)
-    if a.qk_norm:
-        k = _rms(k, w["k_norm"], a.norm_eps)
-    k = _rope(k, pos, a.rope_theta)
-    if fp8:
-        k, v = _fq(k, -1), _fq(v, (0, 2))
-    scale = a.head_dim ** -0.5
-
-    def block(args):
-        xb, hb, pb = args
-        q = _mm(hb, w["wq"], fp8).reshape(-1, a.heads, a.head_dim)
-        if a.qk_norm:
-            q = _rms(q, w["q_norm"], a.norm_eps)
-        q = _rope(q, pb, a.rope_theta)
-        if fp8:
-            q = _fq(q, -1)
-        qg = q.reshape(-1, a.kv_heads, g, a.head_dim)
-        sc = jnp.einsum("qkgd,tkd->kgqt", qg, k, precision=HI) * scale
-        causal = pos[None, :] <= pb[:, None]
-        sc = jnp.where(causal[None, None], sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        if fp8:
-            p = _fq(p, -1)
-        o = jnp.einsum("kgqt,tkd->qkgd", p, v, precision=HI)
-        xb = xb + _mm(o.reshape(-1, a.q_width), w["wo"], fp8)
-        hb = _rms(xb, w["norm2"], a.norm_eps)
-        f = jax.nn.silu(_mm(hb, w["w_gate"], fp8)) * _mm(hb, w["w_up"], fp8)
-        return xb + _mm(f, w["w_down"], fp8)
-
-    nb = t // Q_BLOCK
-    out = jax.lax.map(block, (x.reshape(nb, Q_BLOCK, -1),
-                              h.reshape(nb, Q_BLOCK, -1),
-                              pos.reshape(nb, Q_BLOCK)))
-    return out.reshape(t, -1)
-
-
 @functools.partial(jax.jit, static_argnames=("a",))
 def _embed(table, tokens, *, a: Arch):
     return table[tokens].astype(jnp.float32)
@@ -117,12 +70,12 @@ def _embed(table, tokens, *, a: Arch):
 
 @functools.partial(jax.jit, static_argnames=("a", "fp8"))
 def _head(w, x, rows, *, a: Arch, fp8: bool):
-    h = _rms(x[rows], w["final_norm"].astype(jnp.float32), a.norm_eps)
+    h = rms(x[rows], w["final_norm"].astype(jnp.float32), a.norm_eps)
     if a.tied:
         table = w["embed"].astype(jnp.float32).T
     else:
         table = w["unembed"].astype(jnp.float32)
-    return _mm(h, table, fp8)
+    return mm(h, table, fp8)
 
 
 def logits_at(w: dict, a: Arch, tokens: np.ndarray, rows: Sequence[int],
@@ -136,8 +89,7 @@ def logits_at(w: dict, a: Arch, tokens: np.ndarray, rows: Sequence[int],
     toks[:n] = tokens
     pos = jnp.arange(t, dtype=jnp.int32)
     x = _embed(w["embed"], jnp.asarray(toks), a=a)
-    for li in range(a.layers):
-        x = _layer(w["layers"], li, x, pos, a=a, fp8=fp8)
+    x = family_of(a).layers(w, x, pos, a, fp8)
     r = np.zeros(-(-len(rows) // 64) * 64, np.int32)
     r[:len(rows)] = rows
     head_w = {k: w[k] for k in ("final_norm", "embed", "unembed") if k in w}

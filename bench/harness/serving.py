@@ -8,7 +8,6 @@ in the order ``serve`` asks for them.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -19,18 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from harness import traffic
-from harness.arch import Arch
-
-
-def program_config(a: Arch):
-    """The program's model config with every size taken from the file."""
-    from repro.configs import get_config
-    return dataclasses.replace(
-        get_config(a.program_arch), name=a.name, num_layers=a.layers,
-        d_model=a.d_model, num_heads=a.heads, num_kv_heads=a.kv_heads,
-        head_dim=a.head_dim, d_ff=a.d_ff, vocab_size=a.vocab,
-        rope_theta=a.rope_theta, norm_eps=a.norm_eps,
-        tie_embeddings=a.tied, use_qk_norm=a.qk_norm)
+from harness.arch import Arch, family_of
 
 
 def build_model(a: Arch, params: dict):
@@ -38,7 +26,8 @@ def build_model(a: Arch, params: dict):
     the tree, shapes and dtypes the program's own init would make."""
     from repro.models.model import build_model as program_build
     dt = jnp.dtype(a.dtype)
-    model = program_build(program_config(a), param_dtype=dt, compute_dtype=dt)
+    model = program_build(family_of(a).program_config(a), param_dtype=dt,
+                          compute_dtype=dt)
     want = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     got = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                        params)

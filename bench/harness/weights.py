@@ -1,22 +1,23 @@
 """Weights drawn from the seed, on the device, in one jitted call.
 
 The benchmark makes the weights itself, so the reference never takes
-anything the program made.  Matrices are normal with variance
-1/fan-in, the embedding (and the untied head) with variance 1/d_model, so
-logits have a spread near 1 at any width; norm scales are 1 + 0.1·N so a
-forgotten scale shows.  Layers are drawn one at a time inside the call
-(``lax.map``), and the vocabulary tables in row blocks, so no full-size float32
-copy of a weight exists beside the bfloat16 result.
+anything the program made.  Each family's ``draw`` builds them from the
+draws here: matrices are normal with variance 1/fan-in, the embedding
+(and the untied head) with variance 1/d_model, so logits have a spread
+near 1 at any width; norm scales are 1 + 0.1·N so a forgotten scale
+shows.  Layers are drawn one at a time inside the call (``lax.map``), and
+the vocabulary tables in row blocks, so no full-size float32 copy of a
+weight exists beside the result in the served dtype.
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.arch import Arch
+from harness.arch import Arch, family_of
 
 Weights = Dict[str, object]
 
@@ -35,69 +36,38 @@ def _row_blocks(rows: int) -> int:
     return 1
 
 
-def _draw(a: Arch, dt):
-    def normal(key, shape, std):
-        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dt)
+def normal(key, shape, std: float, dt):
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dt)
 
-    def scale(key, n):
-        return (1.0 + 0.1 * jax.random.normal(key, (n,), jnp.float32)
-                ).astype(dt)
 
-    def layer(key):
-        k = jax.random.split(key, 11)
-        d, q, kv, f = a.d_model, a.q_width, a.kv_width, a.d_ff
-        w = {"norm1": scale(k[0], d), "norm2": scale(k[1], d),
-             "wq": normal(k[2], (d, q), d ** -0.5),
-             "wk": normal(k[3], (d, kv), d ** -0.5),
-             "wv": normal(k[4], (d, kv), d ** -0.5),
-             "wo": normal(k[5], (q, d), q ** -0.5),
-             "w_gate": normal(k[6], (d, f), d ** -0.5),
-             "w_up": normal(k[7], (d, f), d ** -0.5),
-             "w_down": normal(k[8], (f, d), f ** -0.5)}
-        if a.qk_norm:
-            w["q_norm"] = scale(k[9], a.head_dim)
-            w["k_norm"] = scale(k[10], a.head_dim)
-        return w
+def scale(key, n: int, dt):
+    return (1.0 + 0.1 * jax.random.normal(key, (n,), jnp.float32)).astype(dt)
 
-    def table(key, rows: int, cols: int):
-        nb = _row_blocks(rows)
-        blocks = jax.lax.map(
-            lambda kk: normal(kk, (rows // nb, cols), a.d_model ** -0.5),
-            jax.random.split(key, nb))
-        return blocks.reshape(rows, cols)
 
-    def make(key):
-        k = jax.random.split(key, 4)
-        w = {"embed": table(k[0], a.vocab, a.d_model),
-             "final_norm": scale(k[1], a.d_model),
-             "layers": jax.lax.map(layer, jax.random.split(k[2], a.layers))}
-        if not a.tied:
-            w["unembed"] = table(k[3], a.d_model, a.vocab)
-        return w
+def table(key, rows: int, cols: int, std: float, dt):
+    """A (rows, cols) normal table drawn in row blocks."""
+    nb = _row_blocks(rows)
+    blocks = jax.lax.map(lambda kk: normal(kk, (rows // nb, cols), std, dt),
+                         jax.random.split(key, nb))
+    return blocks.reshape(rows, cols)
 
-    return make
+
+def draw_model(a: Arch, key, layers: Callable) -> Weights:
+    """The embedding, the final norm, the head where it is not tied, and
+    under ``"layers"`` what ``layers(key)`` draws; call it inside the
+    family's jitted ``draw``."""
+    dt = jnp.dtype(a.dtype)
+    k = jax.random.split(key, 4)
+    std = a.d_model ** -0.5
+    w = {"embed": table(k[0], a.vocab, a.d_model, std, dt),
+         "final_norm": scale(k[1], a.d_model, dt),
+         "layers": layers(k[2])}
+    if not a.tied:
+        w["unembed"] = table(k[3], a.d_model, a.vocab, std, dt)
+    return w
 
 
 def make_weights(a: Arch, seed: int) -> Weights:
     """All weights of ``a`` in its served dtype, drawn on the default
     device by one compiled program."""
-    dt = jnp.dtype(a.dtype)
-    return jax.jit(_draw(a, dt))(seed_key(seed))
-
-
-def program_params(w: Weights) -> dict:
-    """The same arrays arranged as the program's parameter tree (a
-    uniform layer stack under ``scan_layers``); nothing is copied."""
-    L = w["layers"]
-    attn = {k: L[k] for k in ("wq", "wk", "wv", "wo")}
-    if "q_norm" in L:
-        attn["q_norm"], attn["k_norm"] = L["q_norm"], L["k_norm"]
-    p = {"embed": w["embed"], "final_norm": {"scale": w["final_norm"]},
-         "prefix_layers": [],
-         "scan_layers": {"norm1": {"scale": L["norm1"]},
-                         "norm2": {"scale": L["norm2"]}, "attn": attn,
-                         "mlp": {k: L[k] for k in ("w_gate", "w_up",
-                                                   "w_down")}}}
-    if "unembed" in w:
-        p["unembed"] = w["unembed"]
-    return p
+    return family_of(a).draw(a, seed_key(seed))

@@ -47,18 +47,18 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
+from harness.arch import BenchError  # noqa: E402
+
 TRACE_DIR = os.path.join(BENCH, ".trace")
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 
 
-class BenchError(Exception):
-    """The run cannot produce a result (no chip, missing files, ...)."""
-
-
 def load_cell(name: str, root: str = ROOT) -> dict:
-    """The cell's entry and every file it is found by."""
+    """The cell's entry and every file it is found by, all under ``root``:
+    its configuration, the configuration's family, traffic and limits."""
     from harness import arch as arch_mod
     from harness import traffic
+    bench = os.path.join(root, os.path.relpath(BENCH, ROOT))
     path = os.path.join(root, "BENCHMARK.json")
     if not os.path.exists(path):
         raise BenchError(f"no BENCHMARK.json at {root}")
@@ -69,15 +69,16 @@ def load_cell(name: str, root: str = ROOT) -> dict:
         raise BenchError(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
     cfg = {c["name"]: c for c in spec["configs"]}[cell["config"]]
-    mix = traffic.load(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
-    with open(os.path.join(BENCH, "limits", name + ".json")) as f:
+    mix = traffic.load(os.path.join(bench, "traffic", cell["traffic"] + ".json"))
+    with open(os.path.join(bench, "limits", name + ".json")) as f:
         limits = json.load(f)
 
     def applies(m):
         return "workloads" not in m or name in m["workloads"]
 
-    return {"cell": cell, "arch": arch_mod.load(os.path.join(root, cfg["file"]),
-                                                cfg["name"]),
+    a = arch_mod.load(os.path.join(root, cfg["file"]), cfg["name"],
+                      os.path.join(bench, "families"))
+    return {"cell": cell, "arch": a,
             "mix": mix, "limits": limits,
             "end_to_end": [m for m in spec["end_to_end"] if applies(m)],
             "per_layer": [m for m in spec["per_layer"] if applies(m)]}
@@ -157,6 +158,7 @@ def run_cell(c: dict, seed: int, seconds: float, traced: bool, *,
     import jax
     from harness import check, peaks as peaks_mod, readers, serving, trace
     from harness import traffic, weights as weights_mod
+    from harness.arch import family_of
     from harness.probe import CompileCounter, Probe
 
     a, mix, cell = c["arch"], c["mix"], c["cell"]
@@ -168,7 +170,7 @@ def run_cell(c: dict, seed: int, seconds: float, traced: bool, *,
     w = weights_mod.make_weights(a, seed)
     jax.block_until_ready(w)
     log(t_process, f"weights of {a.name} drawn", dev)
-    params = weights_mod.program_params(w)
+    params = family_of(a).program_params(w)
     model = serving.build_model(a, params)
     engine = serving.build_engine(a, model, params, mix["store"])
     probe = Probe(engine, annotate=traced, require_pallas=require_tpu)
