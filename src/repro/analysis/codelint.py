@@ -2,7 +2,7 @@
 
     python -m repro.analysis.codelint [--root PATH] [--json]
 
-Six rules encoding conventions this repo has paid for breaking:
+Seven rules encoding conventions this repo has paid for breaking:
 
   * ``kernel-oracle``   — every ``kernels/<name>/kernel.py`` ships a
     ``ref.py`` NumPy/JAX oracle AND an interpret-mode parity test (a test
@@ -34,6 +34,9 @@ Six rules encoding conventions this repo has paid for breaking:
     declared in ``SPAN_CATALOG`` (the span recorder, ``obs/spans.py``,
     records whatever it is given; the benchmark's readers find spans by
     these names).
+  * ``scope-catalog``   — likewise every ``jax.named_scope`` literal under
+    ``src/repro`` is declared in ``SCOPE_CATALOG`` (the benchmark sums a
+    device trace's ops by these names).
 
 Each ``check_*`` function takes explicit paths so the mutation self-tests
 can point them at synthetic files.
@@ -287,6 +290,9 @@ METRIC_METHODS = {"counter", "gauge", "histogram"}
 #: span-recorder entry points whose first positional string argument is a
 #: span name (``obs/spans.py``; called bare or as a module attribute)
 SPAN_FUNCTIONS = {"span", "instant"}
+#: ``jax.named_scope`` (called as ``jax.named_scope`` or bare): its first
+#: positional string argument is a scope name of the compiled programs
+SCOPE_FUNCTIONS = {"named_scope"}
 
 
 def _catalog_names(registry_py: Path, catalog: str = "METRIC_CATALOG"):
@@ -347,6 +353,24 @@ def check_span_catalog(registry_py: Path,
         if name not in registered]
 
 
+def check_scope_catalog(registry_py: Path,
+                        paths: Sequence[Path]) -> List[CodeLintFinding]:
+    registered = (_catalog_names(registry_py, "SCOPE_CATALOG")
+                  if registry_py.exists() else None)
+    if registered is None:
+        return [CodeLintFinding(
+            "scope-catalog", str(registry_py), 1,
+            "no SCOPE_CATALOG literal dict found — the scope catalog is "
+            "gone")]
+    return [CodeLintFinding(
+        "scope-catalog", str(path), line,
+        f"scope {name!r} used at a {func}() call but not declared in "
+        f"SCOPE_CATALOG (obs/registry.py)")
+        for path, line, func, name in _literal_calls(paths, SCOPE_FUNCTIONS,
+                                                     bare=True)
+        if name not in registered]
+
+
 def _literal_calls(paths: Sequence[Path], funcs, *, bare: bool):
     """``(path, line, function, literal)`` of every call to one of
     ``funcs`` — as an attribute, and with ``bare`` as a plain name too —
@@ -386,6 +410,8 @@ def run_all(root: Path) -> List[CodeLintFinding]:
                                      sorted(src.rglob("*.py")))
     findings += check_span_catalog(src / "obs" / "registry.py",
                                    sorted(src.rglob("*.py")))
+    findings += check_scope_catalog(src / "obs" / "registry.py",
+                                    sorted(src.rglob("*.py")))
     return findings
 
 

@@ -30,6 +30,15 @@ class MoEConfig:
     dense_d_ff: int = 0           # d_ff of those dense layers
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.001
+    # expert parallelism: this chip holds routed experts
+    # [expert_offset, expert_offset + experts_held); 0 held = all of them
+    expert_offset: int = 0
+    experts_held: int = 0
+    norm_topk_prob: bool = True   # renormalise the top-k router weights
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -41,6 +50,18 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling (DeepSeek-V2's ``rope_scaling`` of type "yarn")."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -87,6 +108,7 @@ class ModelConfig:
     activation: str = "swiglu"    # swiglu | geglu | gelu (non-gated)
     position: str = "rope"        # rope | sinusoidal | none
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None
     use_qkv_bias: bool = False
     use_qk_norm: bool = False
     tie_embeddings: bool = False

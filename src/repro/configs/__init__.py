@@ -27,10 +27,12 @@ _REGISTRY = {
     "qwen3-8b": "qwen3_8b",
     "llama3.1-8b": "llama31_8b",
     "qwen3-30b-a3b": "qwen3_30b_a3b",
+    # ---- benchmark configurations beyond the two pools ----
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ASSIGNED_ARCHS = tuple(list(_REGISTRY)[:10])
-PAPER_ARCHS = tuple(list(_REGISTRY)[10:])
+PAPER_ARCHS = tuple(list(_REGISTRY)[10:13])
 ALL_ARCHS = tuple(_REGISTRY)
 
 

@@ -130,6 +130,10 @@ class RestorationExecutor:
                 boundaries[s].append(x)
                 for i in range(lo, hi):
                     x, cache = m.layer_chunk(self.params, i, x, pos, cache)
+                    # each call returns a new whole cache: a host that ran
+                    # ahead would hold one per queued call (0.4 GB each for
+                    # DeepSeek-V2-Lite's latents of a 12.6k-token prefix)
+                    jax.block_until_ready(cache)
                 # snapshot recurrent state at end of this chunk for this stage
                 snap = _state_snapshot(cfg, cache)
                 if snap:

@@ -12,9 +12,10 @@ only on real TPUs (interpret mode is far slower than XLA on CPU) and
 otherwise the jitted oracle, which XLA still fuses into one
 dequant+dynamic-update-slice per field — already a single dispatch per
 field instead of one per chunk x field.  The Pallas path additionally
-requires lane-aligned channels and chunk-aligned t0; anything else runs
-the oracle (the destination is aliased in place, so channels cannot be
-pad-and-cropped the way kv_quant's out-of-place ops can).
+requires sublane-aligned chunks and a chunk-aligned token range; anything
+else runs the oracle.  Any channel count is fine: a block spans all of a
+field's channels, which the TPU accepts at any width (an MLA latent's 576
+channels as well as a K/V head group's 1024).
 :func:`pick_backend` says which one a call will take, so callers can count
 oracle runs instead of mistaking them for kernel launches.
 """
@@ -26,25 +27,23 @@ import jax
 
 from repro.kernels.kv_restore import kernel, ref
 
-_LANE = 128
 _SUBLANE = 8
 
 
-def _pallas_ok(caches, *, t0, chunk_size, t):
-    if t % chunk_size or t0 % chunk_size or chunk_size % _SUBLANE:
-        return False
-    return all(c.shape[-1] % _LANE == 0 for c in caches)
+def _pallas_ok(*, t0, chunk_size, t):
+    return not (t % chunk_size or t0 % chunk_size or chunk_size % _SUBLANE)
 
 
 def pick_backend(caches, staged, *, t0: int, chunk_size: int,
                  backend: str = "auto") -> str:
     """The backend a scatter of these shapes runs on: ``auto`` resolves to
     ``pallas`` on TPU and ``ref`` elsewhere, and ``pallas`` becomes ``ref``
-    for unaligned channels or token ranges."""
+    for unaligned chunks or token ranges.  The caches' channel counts do
+    not enter: a block spans all of them."""
     if backend == "auto":
         backend = "pallas" if jax.default_backend() == "tpu" else "ref"
     if backend == "pallas" and not _pallas_ok(
-            caches, t0=t0, chunk_size=chunk_size, t=staged[0].shape[1]):
+            t0=t0, chunk_size=chunk_size, t=staged[0].shape[1]):
         backend = "ref"
     return backend
 

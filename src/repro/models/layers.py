@@ -7,6 +7,8 @@ in ``param_dtype``; compute runs in the dtype of the incoming activations
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -96,18 +98,48 @@ def apply_mlp(params: dict, x: jax.Array, activation: str) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> jax.Array:
+    """Inverse frequencies of the rotary pairs; with a
+    :class:`~repro.config.YarnConfig`, DeepSeek's YaRN blend of the plain
+    (extrapolated) and the ``factor``-stretched (interpolated) ones."""
+    if yarn is None:
+        return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    inter = extra / yarn.factor
+
+    def corr_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                    # 1: plain frequency, 0: stretched
+    return jnp.asarray(inter * (1.0 - keep) + extra * keep, jnp.float32)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention magnitude factor ``0.1·m·ln(s) + 1`` (1 for s ≤ 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
     """x: (..., S, H, Dh) or (..., S, Dh); positions: (..., S)."""
     dh = x.shape[-1]
-    freqs = rope_freqs(dh, theta)                              # (Dh/2,)
+    freqs = rope_freqs(dh, theta, yarn)                        # (Dh/2,)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, Dh/2)
     if x.ndim == angles.ndim + 1:                              # head axis present
         angles = angles[..., None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -22,9 +22,20 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.models.attention import _gqa_flash, _FLASH_THRESHOLD
-from repro.models.layers import apply_rope, dense_init, apply_norm, init_norm
+from repro.models.layers import (apply_norm, apply_rope, dense_init, init_norm,
+                                 yarn_mscale)
 
 NEG_INF = -1e30
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """1/√d_qk, times YaRN's ``mscale²`` where the config scales RoPE with
+    a nonzero ``mscale_all_dim`` (DeepSeek-V2)."""
+    scale = 1.0 / (cfg.qk_head_dim ** 0.5)
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def init_mla(key, cfg: ModelConfig, dtype) -> dict:
@@ -59,7 +70,7 @@ def _project_q(cfg: ModelConfig, params: dict, x: jax.Array, positions: jax.Arra
         q = x @ params["wq"].astype(x.dtype)
     q = q.reshape(b, s, h, qk_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -70,7 +81,7 @@ def compress_kv(cfg: ModelConfig, params: dict, x: jax.Array, positions: jax.Arr
     kv = x @ params["wkv_a"].astype(x.dtype)
     c_kv, k_rope = jnp.split(kv, [m.kv_lora_rank], axis=-1)
     c_kv = apply_norm("rmsnorm", params["kv_norm"], c_kv, cfg.norm_eps)
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return jnp.concatenate([c_kv, k_rope], axis=-1)
 
 
@@ -86,6 +97,11 @@ def _uk_uv(cfg: ModelConfig, params: dict, dtype):
 def mla_full(cfg: ModelConfig, params: dict, x: jax.Array, positions: jax.Array,
              backend: str = "auto"):
     """Full causal MLA (prefill/train). Returns (out, ckv latent for caching)."""
+    with jax.named_scope("mla"):
+        return _mla_full(cfg, params, x, positions, backend)
+
+
+def _mla_full(cfg, params, x, positions, backend):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.num_heads
@@ -99,7 +115,7 @@ def mla_full(cfg: ModelConfig, params: dict, x: jax.Array, positions: jax.Array,
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None], (b, s, h, m.qk_rope_head_dim))],
                         axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = 1.0 / (cfg.qk_head_dim ** 0.5)
+    scale = softmax_scale(cfg)
     if s > _FLASH_THRESHOLD or backend == "flash":
         # pad v to qk dim? no — flash handles differing v dim via separate arg shapes
         out = _gqa_flash(q, k, v, positions, positions, scale, 0)
@@ -120,6 +136,11 @@ def mla_chunk(cfg: ModelConfig, params: dict, x: jax.Array, positions: jax.Array
     x: (B,C,D); ckv_cache: (B,S_cache,lora+rope); kpos: (S_cache,).
     Returns (out, ckv_cache', kpos').
     """
+    with jax.named_scope("mla"):
+        return _mla_chunk(cfg, params, x, positions, ckv_cache, kpos)
+
+
+def _mla_chunk(cfg, params, x, positions, ckv_cache, kpos):
     q_nope, q_rope = _project_q(cfg, params, x, positions)
     ckv = compress_kv(cfg, params, x, positions)
     slot = positions[0]
@@ -128,6 +149,49 @@ def mla_chunk(cfg: ModelConfig, params: dict, x: jax.Array, positions: jax.Array
     out = mla_attend_absorbed(cfg, params, q_nope, q_rope, positions,
                               ckv_cache.astype(x.dtype), kpos)
     return out, ckv_cache, kpos
+
+
+def mla_decode(cfg: ModelConfig, params: dict, x: jax.Array,
+               positions: jax.Array, lat: jax.Array, kpos: jax.Array):
+    """One new token's absorbed attention over a latent cache it does not
+    write: the cached tokens before it (``kpos`` below its position) and
+    the token's own latent, under one softmax.  Scores and values each
+    read the cache once, over all ``lora+rope`` channels, so no part of
+    it is copied.
+
+    x: (B,1,D); lat: (B,S,lora+rope); kpos: (S,).
+    Returns (out, the token's latent (B,1,lora+rope) in ``lat``'s dtype)."""
+    with jax.named_scope("mla"):
+        return _mla_decode(cfg, params, x, positions, lat, kpos)
+
+
+def _mla_decode(cfg, params, x, positions, lat, kpos):
+    m = cfg.mla
+    b, c = x.shape[:2]
+    h = cfg.num_heads
+    dt = x.dtype
+    q_nope, q_rope = _project_q(cfg, params, x, positions)
+    new = compress_kv(cfg, params, x, positions).astype(lat.dtype)
+    w_uk, w_uv = _uk_uv(cfg, params, dt)
+    q_lat = jnp.einsum("bchd,lhd->bchl", q_nope, w_uk)
+    # [q̃ || q_rope] against [c_kv || k_rope]: one contraction per read
+    q = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], axis=-1)
+    lat, cur = lat.astype(dt), new.astype(dt)
+    scale = softmax_scale(cfg)
+    sc = jnp.einsum("bchl,btl->bhct", q, lat,
+                    preferred_element_type=jnp.float32) * scale
+    kp = kpos[None, None, None, :]
+    sc = jnp.where((kp < positions[:, None, :, None]) & (kp >= 0), sc, NEG_INF)
+    sc_cur = jnp.einsum("bchl,bnl->bhcn", q, cur,
+                        preferred_element_type=jnp.float32) * scale
+    top = jnp.maximum(sc.max(-1, keepdims=True), sc_cur)
+    e, e_cur = jnp.exp(sc - top), jnp.exp(sc_cur - top)
+    den = e.sum(-1, keepdims=True) + e_cur
+    o = (jnp.einsum("bhct,btl->bchl", (e / den).astype(dt), lat)
+         + jnp.einsum("bhcn,bnl->bchl", (e_cur / den).astype(dt), cur))
+    out = jnp.einsum("bchl,lhd->bchd", o[..., :m.kv_lora_rank], w_uv)
+    out = out.reshape(b, c, h * m.v_head_dim)
+    return out @ params["wo"].astype(dt), new
 
 
 def mla_attend_absorbed(cfg: ModelConfig, params: dict, q_nope, q_rope,
@@ -141,7 +205,7 @@ def mla_attend_absorbed(cfg: ModelConfig, params: dict, q_nope, q_rope,
     w_uk, w_uv = _uk_uv(cfg, params, x_dtype)
     # absorb W_uk into q: q̃ (B,C,H,lora)
     q_lat = jnp.einsum("bchd,lhd->bchl", q_nope, w_uk)
-    scale = 1.0 / (cfg.qk_head_dim ** 0.5)
+    scale = softmax_scale(cfg)
     sc = jnp.einsum("bchl,btl->bhct", q_lat, c_kv)
     sc += jnp.einsum("bchd,btd->bhct", q_rope, k_rope)
     sc = sc.astype(jnp.float32) * scale

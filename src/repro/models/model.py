@@ -64,6 +64,15 @@ class Model:
         else:
             self.prefix_len = 0
         self.scan_len = cfg.num_layers - self.prefix_len
+        # an MLA stack decodes through ``_decode_latent``
+        self.latent_decode = (cfg.mla is not None and bool(self.scan_len)
+                              and not cfg.attn_window
+                              and set(cfg.layer_kinds()) == {"attention"})
+        # the expert share this model holds, (held, total, offset); the
+        # engine's telemetry reports it as the gauge ``moe.experts_held``
+        m = cfg.moe
+        self.experts_held = (None if m is None else
+                             (m.n_held, m.num_experts, m.expert_offset))
         # compiled entry points of the serving executor.  Built once per
         # Model (models are cached per config), so every executor shares
         # their compile caches.  The layer index and cache slot are traced:
@@ -362,9 +371,19 @@ class Model:
         the cache. The workhorse of token-wise and stage-local restoration."""
         cfg = self.cfg
         hi = cfg.num_layers if hi is None else hi
-        # scan fast-path: whole stack of a uniform arch
-        if cfg.is_uniform and lo == 0 and hi == cfg.num_layers and self.scan_len:
-            kind = cfg.layer_kinds()[0]
+        # scan fast-path over the whole stack: the unrolled prefix layers
+        # (DeepSeek's leading dense layer), then one scan over the uniform
+        # segment.  Every layer is of one kind, so the scan's cache slots
+        # are those from the prefix's end on.
+        kinds = cfg.layer_kinds()
+        if (lo == 0 and hi == cfg.num_layers and self.scan_len
+                and len(set(kinds)) == 1):
+            kind = kinds[0]
+            p = self.prefix_len
+            for i in range(p):
+                x, cache = self._layer_cached(params["prefix_layers"][i],
+                                              kind, self.slots[i][1], x,
+                                              positions, dict(cache))
 
             def body(xc, xs):
                 layer_p, layer_cache = xs
@@ -381,8 +400,12 @@ class Model:
                     return xc, {"shift_tm": stm, "shift_cm": scm, "wkv": wkv}
                 raise ValueError(kind)
 
-            x, new_cache = jax.lax.scan(body, x, (params["scan_layers"], cache))
-            return x, new_cache
+            if not p:
+                return jax.lax.scan(body, x, (params["scan_layers"], cache))
+            x, new = jax.lax.scan(
+                body, x, (params["scan_layers"],
+                          {f: a[p:] for f, a in cache.items()}))
+            return x, {f: cache[f].at[p:].set(a) for f, a in new.items()}
 
         for i in range(lo, hi):
             x, cache = self.layer_chunk(params, i, x, positions, cache)
@@ -496,9 +519,49 @@ class Model:
         positions = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b, 1))
         inp = tokens[:, None] if cfg.input_mode == "tokens" else tokens[:, None, :]
         x = self.embed(params, inp, positions)
-        x, cache = self.stack_chunk(params, x, positions, cache)
+        if self.latent_decode:
+            x, cache = self._decode_latent(params, x, positions, cache)
+        else:
+            x, cache = self.stack_chunk(params, x, positions, cache)
         logits = self.unembed(params, x)
         return logits[:, 0], cache
+
+    def _decode_latent(self, params, x, positions, cache):
+        """One token through an MLA stack, the latent cache read in place:
+        each layer attends to the cache as it stands plus its own new
+        latent (``tfm.latent_layer_decode``), and the new latents of every
+        layer are written into the token's slot once, after the stack.
+        ``stack_chunk`` writes each layer's slot before attending and moves
+        the cache through its scan, several passes of it a token."""
+        lat, kpos = cache["ckv"], cache["kpos"]
+        p = self.prefix_len
+        news = []
+        for i in range(p):
+            x, new = tfm.latent_layer_decode(
+                self.cfg, params["prefix_layers"][i], x, positions, lat[i],
+                kpos[i], moe_groups=self.moe_groups)
+            news.append(new)
+
+        def body(xc, xs):
+            layer_p, j = xs
+            return tfm.latent_layer_decode(
+                self.cfg, layer_p, xc, positions,
+                jax.lax.dynamic_index_in_dim(lat, j, keepdims=False),
+                jax.lax.dynamic_index_in_dim(kpos, j, keepdims=False),
+                moe_groups=self.moe_groups)
+
+        x, scanned = jax.lax.scan(
+            body, x, (params["scan_layers"],
+                      jnp.arange(p, p + self.scan_len, dtype=jnp.int32)))
+        new = jnp.concatenate([jnp.stack(news), scanned]) if p else scanned
+        pos = positions[0, 0]
+        zero = jnp.zeros((), jnp.int32)
+        cache = dict(cache)
+        cache["ckv"] = jax.lax.dynamic_update_slice(
+            lat, new, (zero, zero, pos, zero))
+        cache["kpos"] = jax.lax.dynamic_update_slice(
+            kpos, jnp.full((kpos.shape[0], 1), pos, jnp.int32), (zero, pos))
+        return x, cache
 
     def prefill_chunk(self, params, inputs, cache, start_pos):
         """Chunk prefill against an existing cache (token-wise restoration

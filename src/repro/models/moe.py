@@ -10,6 +10,14 @@ the token all-to-all from the shardings.
 Memory is bounded by ``num_groups``: tokens are processed in sequential
 groups via ``lax.scan``, capping the dispatch buffers at
 ``tokens/num_groups × top_k`` slots (the classic GShard group trick).
+
+Expert share: a chip of an expert-parallel deployment may hold only a
+contiguous range of the routed experts (``MoEConfig.expert_offset``,
+``experts_held``).  The router still scores every expert and picks the
+top-k over all of them; the layer returns the part of the result that the
+held experts give, plus the shared experts.  A route to an expert held
+elsewhere adds nothing here: the shares of all chips sum to the whole
+layer (no exchange between chips is modelled).
 """
 from __future__ import annotations
 
@@ -29,12 +37,13 @@ def init_moe(key, cfg: ModelConfig, dtype) -> dict:
     gated = cfg.activation in ("swiglu", "geglu")
     n_mats = 3 if gated else 2
     ek = jax.random.split(k_exp, n_mats)
+    e = m.n_held      # the router scores every expert; only held ones have weights
     p = {
         "router": dense_init(k_router, (d, m.num_experts), jnp.float32),
-        # stacked expert weights: (E, d, f) / (E, f, d)
-        "w_gate": dense_init(ek[0], (m.num_experts, d, m.expert_d_ff), dtype, in_axis=1),
-        "w_up": dense_init(ek[1 % n_mats], (m.num_experts, d, m.expert_d_ff), dtype, in_axis=1),
-        "w_down": dense_init(ek[-1], (m.num_experts, m.expert_d_ff, d), dtype, in_axis=1),
+        # stacked expert weights: (E_held, d, f) / (E_held, f, d)
+        "w_gate": dense_init(ek[0], (e, d, m.expert_d_ff), dtype, in_axis=1),
+        "w_up": dense_init(ek[1 % n_mats], (e, d, m.expert_d_ff), dtype, in_axis=1),
+        "w_down": dense_init(ek[-1], (e, m.expert_d_ff, d), dtype, in_axis=1),
     }
     if not gated:
         del p["w_gate"]
@@ -63,6 +72,18 @@ def _expert_ffn(params: dict, xb: jax.Array, activation: str) -> jax.Array:
 DISPATCH = "scatter"
 
 
+def route(params: dict, x: jax.Array, moe: MoEConfig):
+    """Router over every expert, held or not: softmax in float32, greedy
+    top-k, renormalised where ``norm_topk_prob``.
+    x: (T, D) -> (probs (T, E), top_p (T, k), top_i (T, k) global ids)."""
+    logits = (x.astype(jnp.float32) @ params["router"]).astype(jnp.float32)   # (T, E)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, moe.top_k)                            # (T, k)
+    if moe.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return probs, top_p, top_i
+
+
 def moe_group(params: dict, x: jax.Array, moe: MoEConfig, activation: str):
     """One group of tokens through the routed experts.
 
@@ -79,32 +100,39 @@ def moe_group(params: dict, x: jax.Array, moe: MoEConfig, activation: str):
 
     from repro.distributed.constraints import constrain
 
-    logits = (x.astype(jnp.float32) @ params["router"]).astype(jnp.float32)   # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)                                    # (T, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)           # renormalise
+    probs, top_p, top_i = route(params, x, moe)
 
-    # --- slot assignment: position of each (token, k) among its expert's hits
+    # --- slot assignment: position of each (token, k) among its expert's
+    # hits, over the held experts (a route to an expert held elsewhere has
+    # no slot and adds nothing here)
     flat_e = top_i.reshape(t * k)                                             # (T·k,)
-    onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)                       # (T·k, E)
+    held = moe.n_held
+    local = flat_e - moe.expert_offset if moe.expert_offset else flat_e       # held index
+    onehot = jax.nn.one_hot(local, held, dtype=jnp.int32)                     # (T·k, E_held)
     pos_in_e = jnp.cumsum(onehot, axis=0) - onehot
-    slot = jnp.take_along_axis(pos_in_e, flat_e[:, None], axis=1)[:, 0]       # (T·k,)
+    is_held = None
+    if held != e:
+        is_held = (local >= 0) & (local < held)
+        local = jnp.clip(local, 0, held - 1)
+    slot = jnp.take_along_axis(pos_in_e, local[:, None], axis=1)[:, 0]        # (T·k,)
     keep = slot < cap
+    if is_held is not None:
+        keep = keep & is_held
 
     # --- scatter tokens into the (E, C, D) buffer; EP: experts over "model",
     # capacity over "data" — GSPMD derives the token all-to-all
     x_rep = jnp.repeat(x, k, axis=0)                                          # (T·k, D)
     x_rep = jnp.where(keep[:, None], x_rep, 0)
     x_rep = constrain(x_rep, ("pod", "data"), None)
-    buf = jnp.zeros((e, cap, d), x.dtype)
-    buf = buf.at[flat_e, jnp.minimum(slot, cap - 1)].add(x_rep)
+    buf = jnp.zeros((held, cap, d), x.dtype)
+    buf = buf.at[local, jnp.minimum(slot, cap - 1)].add(x_rep)
     buf = constrain(buf, "model", "data", None)
 
     y_buf = _expert_ffn(params, buf, activation)                              # (E, C, D)
     y_buf = constrain(y_buf, "model", "data", None)
 
     # --- gather back + combine
-    y_rep = y_buf[flat_e, jnp.minimum(slot, cap - 1)]                         # (T·k, D)
+    y_rep = y_buf[local, jnp.minimum(slot, cap - 1)]                          # (T·k, D)
     y_rep = jnp.where(keep[:, None], y_rep, 0)
     w = (top_p.reshape(t * k, 1)).astype(y_rep.dtype)
     y = (y_rep * w).reshape(t, k, d).sum(axis=1)
@@ -123,15 +151,14 @@ def _moe_group_einsum(params: dict, x: jax.Array, moe: MoEConfig,
     from repro.distributed.constraints import constrain
     t, d = x.shape
     e, k = moe.num_experts, moe.top_k
-    logits = (x.astype(jnp.float32) @ params["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-
-    onehot_e = jax.nn.one_hot(top_i, e, dtype=jnp.float32)           # (T,k,E)
+    probs, top_p, top_i = route(params, x, moe)
+    held = moe.n_held
+    # one-hot over the held experts: a route elsewhere is all zeros
+    onehot_e = jax.nn.one_hot(top_i - moe.expert_offset, held,
+                              dtype=jnp.float32)                     # (T,k,E_held)
     # position of each (t,k) hit within its expert
-    pos = jnp.cumsum(onehot_e.reshape(t * k, e), axis=0) - onehot_e.reshape(t * k, e)
-    slot = (pos.reshape(t, k, e) * onehot_e).sum(-1).astype(jnp.int32)  # (T,k)
+    pos = jnp.cumsum(onehot_e.reshape(t * k, held), axis=0) - onehot_e.reshape(t * k, held)
+    slot = (pos.reshape(t, k, held) * onehot_e).sum(-1).astype(jnp.int32)  # (T,k)
     keep = slot < cap
     onehot_c = jax.nn.one_hot(slot, cap, dtype=x.dtype) * keep[..., None]
     # dispatch (T,E,C) = Σ_k onehot_e ⊗ onehot_c
@@ -147,7 +174,7 @@ def _moe_group_einsum(params: dict, x: jax.Array, moe: MoEConfig,
     y = jnp.einsum("tec,ecd->td", comb, y_buf)
 
     me = probs.mean(axis=0)
-    ce = onehot_e.sum(axis=(0, 1)) / (t * k)
+    ce = jax.nn.one_hot(top_i, e, dtype=jnp.float32).sum(axis=(0, 1)) / (t * k)
     aux = e * jnp.sum(me * ce) * moe.router_aux_loss
     return y, aux
 
@@ -157,6 +184,11 @@ def apply_moe(params: dict, x: jax.Array, cfg: ModelConfig, num_groups: int = 0)
     memory by scanning groups of the SEQUENCE axis sequentially (grouping
     along S keeps the batch-axis sharding intact — grouping along B would
     force a gather whenever groups < batch shards)."""
+    with jax.named_scope("moe"):
+        return _apply_moe(params, x, cfg, num_groups)
+
+
+def _apply_moe(params, x, cfg, num_groups):
     m = cfg.moe
     b, s, d = x.shape
     if num_groups <= 1 or s % num_groups:

@@ -121,3 +121,16 @@ def attention_layer_cached(cfg: ModelConfig, p: dict, x, positions, layer_cache:
     h = apply_norm(cfg.norm, p["norm2"], x, cfg.norm_eps)
     f, _ = _ffn(cfg, p, h, moe_groups)
     return x + f, new_cache
+
+
+def latent_layer_decode(cfg: ModelConfig, p: dict, x, positions, lat, kpos,
+                        *, moe_groups: int = 0):
+    """One decode token of an MLA layer against its latent cache ``lat``
+    (B,S,d_c) and ``kpos`` (S,), both read only.  Returns (x', the token's
+    latent (B,1,d_c)), which the caller writes at the token's slot."""
+    h = apply_norm(cfg.norm, p["norm1"], x, cfg.norm_eps)
+    a, new = mla_mod.mla_decode(cfg, p["attn"], h, positions, lat, kpos)
+    x = x + a
+    h = apply_norm(cfg.norm, p["norm2"], x, cfg.norm_eps)
+    f, _ = _ffn(cfg, p, h, moe_groups)
+    return x + f, new

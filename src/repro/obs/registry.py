@@ -19,7 +19,8 @@ pattern as the ``EVENT_KINDS`` trace-schema rule), and the registry enforces
 the type and exact label keys at instantiation time.
 
 :data:`SPAN_CATALOG` does the same for the names of the in-program spans
-(``obs/spans.py``).
+(``obs/spans.py``), and :data:`SCOPE_CATALOG` for the ``jax.named_scope``
+names of the compiled programs.
 """
 from __future__ import annotations
 
@@ -93,6 +94,12 @@ METRIC_CATALOG = {
     # then the pool write (core/executor.py)
     "decode.fused_tokens_total": {
         "type": "counter", "labels": (), "layer": "core/executor"},
+    # ---- model (models/model.py) ----
+    # routed experts this chip holds per expert layer (value), of how many
+    # the router scores (``total``), from which expert (``offset``)
+    "moe.experts_held": {
+        "type": "gauge", "labels": ("total", "offset"),
+        "layer": "models/model"},
     # ---- storage tiers (storage/placement.py, storage/chunkstore.py) ----
     "storage.tier_used_bytes": {
         "type": "gauge", "labels": ("tier",), "layer": "storage/placement"},
@@ -162,6 +169,22 @@ SPAN_CATALOG = {
     "token": {
         "layer": "core/executor",
         "read_by": "Telemetry.snapshot()['spans'] (serve --metrics-out)"},
+}
+
+
+#: The named-scope catalog: ``jax.named_scope`` names the program puts
+#: around ops in its compiled programs -> {layer, read_by}.  The scope
+#: path reaches the HLO ``op_name`` metadata, so a device trace's op
+#: events can be summed by scope.  A PURE LITERAL: codelint checks every
+#: ``named_scope`` literal under ``src/repro`` against it.
+SCOPE_CATALOG = {
+    # the routed experts (router, dispatch, held experts, combine) and the
+    # shared experts of an expert layer
+    "moe": {"layer": "models/moe",
+            "read_by": "device_ms_per_token.moe"},
+    # MLA attention: projections, latent cache write, attend, output
+    "mla": {"layer": "models/mla",
+            "read_by": "device_ms_per_token.mla"},
 }
 
 

@@ -21,6 +21,7 @@ the run began (``clock["t0_ns"]`` in the snapshot, on
   * benefit-gate and prefetch-gate outcomes, preempt/evict/abort counts,
   * per-resource busy seconds and (real mode) measured per-channel GB/s
     from the fused datapath's ``TransferStream`` counters,
+  * the served model's expert share (``moe.experts_held``),
   * storage-tier occupancy bytes and the hit/miss/promote/demote counters
     from whichever KV store the engine runs against,
   * per-request phase-transition timestamps
@@ -193,6 +194,7 @@ class Telemetry:
                 resource=resource).set(busy[resource])
         self._sweep_datapath()
         self._sweep_storage()
+        self._sweep_model()
         self._spans = (self._spans[0], len(spans.log().records))
 
     def _sweep_datapath(self) -> None:
@@ -222,6 +224,15 @@ class Telemetry:
                 self.registry.gauge(
                     "datapath.channel_gbps",
                     channel=str(c)).set(bw / 1e9)
+
+    def _sweep_model(self) -> None:
+        """The expert share of the served model (real mode)."""
+        ex = getattr(getattr(self.core, "backend", None), "executor", None)
+        held = getattr(getattr(ex, "model", None), "experts_held", None)
+        if held is not None:
+            n, total, offset = held
+            self.registry.gauge("moe.experts_held", total=str(total),
+                                offset=str(offset)).set(n)
 
     def _sweep_storage(self) -> None:
         ks = getattr(self.core, "kvstore", None)

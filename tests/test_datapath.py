@@ -233,19 +233,20 @@ def test_io_channel_devices_and_stream_routing():
     assert dp.stream_for(4) is dp.streams[1]      # modulo wrap
 
 
-@pytest.mark.parametrize("backend,kv_heads,pallas", [
-    ("auto", 2, False),           # CPU: the jitted oracle
-    ("pallas", 2, False),         # 64 channels, not lane-aligned: oracle
-    ("interpret", 4, True),       # 128 channels: the kernel runs
-], ids=["auto-cpu", "unaligned", "aligned"])
+@pytest.mark.parametrize("backend,kv_heads,block,pallas", [
+    ("auto", 2, 8, False),        # CPU: the jitted oracle
+    ("pallas", 2, 4, False),      # 4-token blocks, not sublane-aligned: oracle
+    ("interpret", 2, 8, True),    # 64 channels: the kernel runs
+    ("interpret", 4, 8, True),    # 128 channels: the kernel runs
+], ids=["auto-cpu", "unaligned", "narrow", "aligned"])
 def test_staged_runs_counted_by_what_scattered_them(backend, kv_heads,
-                                                    pallas):
+                                                    block, pallas):
     """Every staged run counts once, as a Pallas launch or as an oracle
     run — an unaligned shape that falls back to the oracle is never
-    counted as a kernel launch."""
+    counted as a kernel launch.  Any channel count takes the kernel."""
     cfg = get_config("qwen3-8b").reduced(num_kv_heads=kv_heads)
     m = build_model(cfg)
-    store = ChunkStore(chunk_size=8, default_tier="host")
+    store = ChunkStore(chunk_size=block, default_tier="host")
     dp = RestoreDatapath.for_channels(1, backend=backend)
     ex = RestorationExecutor(m, m.init(RNG), chunk_size=16,
                              chunk_store=store, datapath=dp)

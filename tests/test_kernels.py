@@ -179,6 +179,7 @@ def test_kv_quant_round_trip_error_bound(dtype):
     (4, 30, 128, 8, 16, 2),       # odd tail: t0+T=32 > S=30 (boundary clip)
     (2, 20, 256, 4, 0, 5),        # whole prefix, many chunks
     (3, 9, 128, 8, 8, 1),        # single tail chunk, 7 padded rows clipped
+    (3, 24, 576, 8, 8, 2),       # an MLA latent: 512 + 64, not lane-aligned
 ])
 def test_kv_restore_kernel_matches_ref(dtype, a, s, c, cs, t0, nch):
     from repro.kernels.kv_restore import ops as kr_ops

@@ -281,3 +281,34 @@ def test_codelint_repo_span_literals_all_declared():
     root = Path(repro.analysis.__file__).resolve().parents[3]
     found = [f for f in run_all(root) if f.rule == "span-catalog"]
     assert found == [], [str(f) for f in found]
+
+
+def test_codelint_mutation_scope_catalog(tmp_path):
+    from repro.analysis.codelint import check_scope_catalog
+    reg = tmp_path / "registry.py"
+    reg.write_text('SCOPE_CATALOG = {"moe": {"layer": "x"}}\n')
+    mod = tmp_path / "mod.py"
+    mod.write_text("import jax\n"
+                   "from jax import named_scope\n"
+                   "def f():\n"
+                   "    with jax.named_scope('moe'):\n"
+                   "        pass\n"
+                   "    with named_scope('ghost'):\n"
+                   "        pass\n")
+    found = check_scope_catalog(reg, [mod])
+    assert [f.rule for f in found] == ["scope-catalog"]
+    assert "'ghost'" in found[0].message
+    reg.write_text("SCOPE_CATALOG = build()\n")
+    assert [f.rule for f in check_scope_catalog(reg, [mod])] \
+        == ["scope-catalog"]
+
+
+def test_codelint_repo_scope_literals_all_declared():
+    from repro.analysis.codelint import run_all
+    import repro.analysis
+    from pathlib import Path
+    from repro.obs import SCOPE_CATALOG
+    root = Path(repro.analysis.__file__).resolve().parents[3]
+    found = [f for f in run_all(root) if f.rule == "scope-catalog"]
+    assert found == [], [str(f) for f in found]
+    assert set(SCOPE_CATALOG) == {"moe", "mla"}

@@ -82,6 +82,21 @@ def test_kv_restore_compiles_for_v5e(one_chip, no_persistent_cache, staging,
     _assert_kernel(compiled)
 
 
+def test_kv_restore_compiles_for_v5e_at_the_latent_width(one_chip,
+                                                         no_persistent_cache):
+    """DeepSeek-V2-Lite's latent cache: 27 slots of 512 + 64 = 576
+    channels (not a multiple of the 128 lanes), 512-token chunks; a
+    block spans every channel, so the kernel takes it as it is."""
+    from repro.kernels.kv_restore import kernel
+    slots, c, cs = 27, 576, 512
+    caches = (_sds((slots, 8 * cs, c), jnp.bfloat16, one_chip),)
+    staged = (_sds((slots, 2 * cs, c), jnp.bfloat16, one_chip),)
+    compiled = kernel.kv_restore_call.lower(
+        caches, staged, None, t0=4 * cs, slot_lo=0, n_slots=slots,
+        cs=cs).compile()
+    _assert_kernel(compiled)
+
+
 # (rows, channels): two chunks of all KV channels, and the store's view
 # of one chunk (slots x tokens x kv heads rows, head_dim channels)
 QUANT_SHAPES = [(2 * CHUNK, C), (SLOTS * CHUNK * 8, 128)]
@@ -103,3 +118,35 @@ def test_kv_dequantize_compiles_for_v5e(one_chip, no_persistent_cache, rows,
     s = _sds((1, cols), jnp.float32, one_chip)
     _assert_kernel(kernel.kv_dequantize_2d.lower(
         q, s, dtype=jnp.bfloat16).compile())
+
+
+def test_latent_decode_compiles_for_v5e_without_copying_the_cache(
+        one_chip, no_persistent_cache):
+    """DeepSeek-V2-Lite's decode token at one expert-parallel chip's share
+    (8 of 64 routed experts) over 8192 cached tokens: the latent cache is
+    read in place, so the program's temporaries are a small part of the
+    cache it attends to (the decode through ``stack_chunk`` copied the
+    cache around its scan: twice the cache in temporaries)."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.core.executor import _decode_program
+    from repro.models.model import build_model
+    base = get_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(
+        base.moe, experts_held=8))
+    m = build_model(cfg, param_dtype=jnp.bfloat16,
+                    compute_dtype=jnp.bfloat16)
+    assert m.latent_decode
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = shapes(jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0))))
+    cache = shapes(jax.eval_shape(lambda: m.init_cache(1, 8192)))
+    compiled = _decode_program.lower(
+        params, _sds((1,), jnp.int32, one_chip), cache,
+        _sds((), jnp.int32, one_chip), step=m.decode_step_jit,
+        embed_dim=0).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 8
